@@ -10,9 +10,9 @@ Two sections:
   SimilarityPlan's group shapes — per half-edge the kernel gathers a
   pe-wide probe row and a te-wide target row (ids + weights, 8 bytes per
   element) and runs pe binary searches over te targets plus the σ
-  multiply-accumulate epilogue. Peaks are nominal single-socket CPU
-  numbers; override with REPRO_PEAK_GFLOPS / REPRO_PEAK_GBPS for your
-  machine (or a device backend).
+  multiply-accumulate epilogue. Peaks come from :data:`PEAKS`, the
+  published per-chip numbers keyed by jax's ``device_kind``; a device
+  that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -63,10 +63,26 @@ def table(recs, *, only_baseline=True):
     return "\n".join(rows)
 
 
-# nominal single-socket CPU peaks; env-overridable so the fraction column
-# is meaningful on whatever actually runs the bench
-PEAK_GFLOPS = float(os.environ.get("REPRO_PEAK_GFLOPS", 50.0))
-PEAK_GBPS = float(os.environ.get("REPRO_PEAK_GBPS", 20.0))
+# published per-chip peaks, keyed by ``jax.devices()[0].device_kind``
+PEAKS = {
+    "TPU v5 lite": {"gflops": 197_000.0, "gbps": 819.0,
+                    "source": "Google Cloud TPU v5e (bf16 peak, HBM)"},
+}
+
+
+def device_peaks(kind: str | None = None) -> dict:
+    """The :data:`PEAKS` row of ``kind`` (default: the first jax device).
+    An unlisted device raises: a roofline share against guessed peaks
+    would be a made-up number."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+    if kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {kind!r}; add a row with "
+            f"its source to benchmarks/roofline.PEAKS (known: {sorted(PEAKS)})")
+    return PEAKS[kind]
+
 
 SIM_GRAPHS = ("sparse-8k", "powerlaw-8k")
 
@@ -99,8 +115,10 @@ def similarity_section():
 
     lines = []
     pol = default_policy()
+    peaks = device_peaks()
     print(f"\n### similarity pass (platform {pol.platform()}, "
-          f"peaks {PEAK_GFLOPS:.0f} GFLOP/s / {PEAK_GBPS:.0f} GB/s)\n")
+          f"peaks {peaks['gflops']:.0f} GFLOP/s / {peaks['gbps']:.0f} GB/s, "
+          f"{peaks['source']})\n")
     print("| graph | lane | m | GB | GFLOP | ms | GB/s | GFLOP/s "
           "| AI F/B | dominant | frac |")
     print("|" + "---|" * 11)
@@ -114,8 +132,8 @@ def similarity_section():
         lane = pol.lane("bucket_probe", width=widest)
         gbps = model_bytes / t / 1e9
         gflops = flops / t / 1e9
-        t_mem = model_bytes / (PEAK_GBPS * 1e9)
-        t_cmp = flops / (PEAK_GFLOPS * 1e9)
+        t_mem = model_bytes / (peaks["gbps"] * 1e9)
+        t_cmp = flops / (peaks["gflops"] * 1e9)
         dominant = "memory" if t_mem >= t_cmp else "compute"
         frac = max(t_mem, t_cmp) / t
         print(f"| {gname} | {lane} | {g.m} | {model_bytes / 1e9:.3f} "

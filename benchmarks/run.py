@@ -72,6 +72,8 @@ def main() -> None:
         from repro.core.distributed import force_host_devices
         force_host_devices(args.shards)
 
+    from repro.backend.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     lines: list[str] = []
     if "fig5" in sections:

@@ -50,10 +50,11 @@ def pad1(a: np.ndarray, pad: int, fill) -> np.ndarray:
     return np.concatenate([a, np.full(pad, fill, dtype=a.dtype)])
 
 
-def pad_to(x: jax.Array, mult: int, axes) -> jax.Array:
-    """Zero-pad ``axes`` of ``x`` up to the next multiple of ``mult``."""
+def pad_to(x: jax.Array, mult: int, axes, value=0) -> jax.Array:
+    """Pad ``axes`` of ``x`` with ``value`` (default zero) up to the next
+    multiple of ``mult``."""
     pads = [(0, 0)] * x.ndim
     for ax in axes:
         rem = (-x.shape[ax]) % mult
         pads[ax] = (0, rem)
-    return jnp.pad(x, pads)
+    return jnp.pad(x, pads, constant_values=value)

@@ -21,7 +21,8 @@ hard-coded at each call site. The policy centralizes all of it:
   (read per call, so tests and subprocesses can pin a lane) or an
   explicit ``forced_lane=`` (``EngineConfig(lane=...)`` / ``scan_serve
   --lane``). A forced lane clamps to each op's available lanes (ops with
-  only a ``ref`` lane stay on it).
+  only a ``ref`` lane stay on it). ``pallas-interpret`` is a host lane:
+  forcing it on a TPU raises instead of emulating the kernels there.
 
 The **bit-identity contract** makes lane choice safe: every lane of every
 hot op reproduces the ``ref`` lane bit-for-bit on unweighted σ (to ULP on
@@ -86,11 +87,19 @@ class ExecutionPolicy:
 
     def forced_lane(self) -> Optional[str]:
         """The pinned lane, if any: ``REPRO_LANE`` env (read per call)
-        beats the constructor/``EngineConfig`` override."""
+        beats the constructor/``EngineConfig`` override.
+
+        Pinning ``pallas-interpret`` on a TPU raises: the interpreter would
+        emulate every kernel on the host while the chip sits idle, a
+        silent fallback no caller on the chip wants."""
         env = os.environ.get(ENV_LANE)
-        if env:
-            return _check_lane(env)
-        return self._forced
+        forced = _check_lane(env) if env else self._forced
+        if forced == LANE_INTERPRET and self.platform() == "tpu":
+            raise RuntimeError(
+                f"lane {LANE_INTERPRET!r} is forced on a TPU; it would run "
+                "every kernel in the host interpreter. Unset REPRO_LANE / "
+                "EngineConfig.lane, or force 'ref' or 'pallas-compiled'.")
+        return forced
 
     def pallas_lane(self) -> str:
         """Which Pallas flavor this platform runs: compiled on TPU,

@@ -42,8 +42,8 @@ class AutotuneProfile:
     gram_block: int = 128         # masked_gram bm/bn/bk (triangle_count op)
     probe_be: int = 256           # bucket_probe edge-block
     probe_bt: int = 256           # bucket_probe target-tile stream width
-    simhash_block: int = 128      # simhash_pack bm/bk (bs fixed at 128)
-    hamming_block: int = 1024     # hamming_cosine edge-block
+    simhash_block: int = 128      # simhash_pack bm/bk (all k samples per block)
+    hamming_block: int = 1024     # hamming_distance edge-block
     # interpret-mode grids unroll at trace time, so the interpret lane caps
     # similarity chunks to keep compile time bounded (compiled lane ignores)
     probe_interpret_chunk: int = 512
@@ -150,7 +150,7 @@ def autotune(policy=None, *, candidates: Optional[dict] = None,
             timed += len(cand["probe_block"])
 
         if len(cand["hamming_block"]) > 1 and lane != LANE_REF:
-            from repro.kernels.hamming import hamming_cosine
+            from repro.kernels.hamming import hamming_distance
             rng = np.random.default_rng(2)
             e, words = 2048, 8
             sk = jnp.asarray(
@@ -159,9 +159,8 @@ def autotune(policy=None, *, candidates: Optional[dict] = None,
             best = min(
                 cand["hamming_block"],
                 key=lambda b: _median_seconds(
-                    lambda: hamming_cosine(sk[0], sk[1], samples=words * 32,
-                                           be=min(b, e),
-                                           interpret=interpret), trials))
+                    lambda: hamming_distance(sk[0], sk[1], be=min(b, e),
+                                             interpret=interpret), trials))
             chosen["hamming_block"] = int(best)
             timed += len(cand["hamming_block"])
 

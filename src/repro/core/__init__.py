@@ -17,7 +17,7 @@ from repro.core.similarity import (
     plan_for,
     triangle_counts,
 )
-from repro.core.index import ScanIndex, build_index, co_core_prefix, get_cores
+from repro.core.index import ScanIndex, build_index, get_cores
 from repro.core.query import ClusterResult, query, query_batch, hubs_outliers
 from repro.core.local import (SeedBatchResult, SeedResult, query_seeds,
                               query_seeds_device)
@@ -42,7 +42,6 @@ from repro.core.quality import (adjusted_rand_index, core_precision_recall,
                                 modularity)
 from repro.core.connectivity import (
     connected_components,
-    connected_components_allreduce,
 )
 from repro.core.distributed import (
     ShardedQueryPlan,

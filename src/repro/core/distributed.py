@@ -25,31 +25,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.core.connectivity import connected_components_allreduce
+from repro.core.connectivity import RowLayout, row_steps
 from repro.core.graph import CSRGraph
 from repro.core import lsh as lsh_mod
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sp", "st", "measure", "mesh", "axis"))
+    jax.jit, static_argnames=("sp", "st", "measure", "mesh", "axis",
+                              "kernel"))
 def _sharded_bucket_group(p0, pt, t0, tt, eu, ev, ew,
                           p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
-                          *, sp, st, measure, mesh, axis):
-    from repro.core.similarity import _bucket_sims_core
+                          *, sp, st, measure, mesh, axis, kernel=None):
+    """One group chunk, edges sharded. ``kernel`` = None runs the jnp
+    engine (``ref`` lane); (be, bt, interpret) runs the Pallas lane."""
+    from repro.core.similarity import (_bucket_sims_core,
+                                       _bucket_sims_pallas_core)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis),) * 7 + (P(None, None),) * 4 + (P(None), P(None)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     def _shard(p0, pt, t0, tt, eu, ev, ew, p_n, p_w, t_n, t_w, nrm, cd):
-        return _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
-                                 p_n, p_w, t_n, t_w, nrm, cd,
-                                 sp, st, measure)
+        if kernel is None:
+            return _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
+                                     p_n, p_w, t_n, t_w, nrm, cd,
+                                     sp, st, measure)
+        return _bucket_sims_pallas_core(p0, pt, t0, tt, eu, ev, ew,
+                                        p_n, p_w, t_n, t_w, nrm, cd,
+                                        sp, st, measure, *kernel)
 
     return _shard(p0, pt, t0, tt, eu, ev, ew,
                   p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg)
@@ -71,12 +78,13 @@ def sharded_edge_similarities(
     ``shard_map`` over its sharded edge chunk, with the two class blocks
     (O(m + n) total, not the old O(n·Δ) padded matrix) replicated.
 
-    The placement inherits the execution policy (``hub_tile`` for a plan
-    built here, lane attribution for the counters); the shard body is the
-    jnp reference engine — the ``ref`` lane — so sharded σ stays
-    bit-identical to the single-host path regardless of forced lanes.
+    Each group resolves its lane through the execution policy exactly as
+    :meth:`SimilarityPlan.edge_sims` does (``hub_tile`` for a plan built
+    here; the shard body runs the jnp engine on the ``ref`` lane and the
+    Pallas probe kernel on the others), and the lanes' bit-identity
+    contract keeps sharded σ equal to the single-host path.
     """
-    from repro.backend.policy import LANE_REF, default_policy
+    from repro.backend.policy import LANE_COMPILED, LANE_REF, default_policy
     from repro.core import similarity as sim_mod
 
     pol = policy if policy is not None else default_policy()
@@ -96,7 +104,6 @@ def sharded_edge_similarities(
     bounds = np.flatnonzero(np.diff(keys[order])) + 1
     out = np.empty(g.m2, np.float32)
     for idx in np.split(order, bounds):
-        pol.note("bucket_probe", LANE_REF)    # shard_map body = jnp engine
         cp = int(plan.vclass[pu[idx[0]]])
         ct = int(plan.vclass[pv[idx[0]]])
         sp = sim_mod._pow2ceil(int(plan.vtiles[pu[idx[0]]]))
@@ -107,9 +114,22 @@ def sharded_edge_similarities(
         # graphs hit the same compiled shard_map shapes
         pe = sp * plan.widths[cp]
         te = st * plan.widths[ct]
+        lane = pol.lane("bucket_probe", width=pe)
+        pol.note("bucket_probe", lane)
         cap = max(sim_mod.CHUNK_ELEMS // max(pe + te, 1), 1)
         cap = 1 << (cap.bit_length() - 1)
+        kernel = None
+        if lane != LANE_REF:
+            if lane == LANE_COMPILED:
+                # the kernel lays each shard's edges on the 128 lanes
+                cap = max(cap, sim_mod.KERNEL_LANE_EDGES * k)
+            else:   # interpret: bound the unrolled grid, as edge_sims does
+                cap = min(cap, sim_mod._pow2ceil(
+                    pol.profile.probe_interpret_chunk) * k)
         csize = -(-min(sim_mod._pow2_bucket(len(idx)), max(cap, 1)) // k) * k
+        if lane != LANE_REF:
+            kernel = (min(pol.profile.probe_be, csize // k),
+                      pol.profile.probe_bt, pol.interpret(lane))
         sent_p = plan.nbr_blocks[cp].shape[0] - 1
         sent_t = plan.nbr_blocks[ct].shape[0] - 1
         for s in range(0, len(idx), csize):
@@ -126,7 +146,8 @@ def sharded_edge_similarities(
                 plan.nbr_blocks[cp], plan.wgt_blocks[cp],
                 plan.nbr_blocks[ct], plan.wgt_blocks[ct],
                 plan.norms, plan.cdeg,
-                sp=sp, st=st, measure=measure, mesh=mesh, axis=axis)
+                sp=sp, st=st, measure=measure, mesh=mesh, axis=axis,
+                kernel=kernel)
             out[sub] = np.asarray(res)[: len(sub)]
     return jnp.asarray(out)
 
@@ -141,11 +162,11 @@ def sharded_simhash_edge_similarities(
     """LSH comparison pass, edges sharded, sketches replicated (k bits/vertex)."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(None, None)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     def _shard(eu, ev, sk):
         return lsh_mod.simhash_edge_similarity(sk, eu, ev, samples)
@@ -157,22 +178,22 @@ def sharded_simhash_edge_similarities(
 # Sharded clustering queries (giant-graph serving path)
 # ---------------------------------------------------------------------------
 # The single-device ``core.query`` holds every O(m) array — half-edges,
-# similarities, the CO slot arrays — on one device. For giant graphs the
+# similarities, the NO slot arrays — on one device. For giant graphs the
 # edge axis is the memory that runs out (GPUSCAN++'s observation), so the
 # sharded query path partitions *every* edge-sized array over the mesh
 # ``data`` axis and keeps only O(n) label/working vectors replicated:
 #
-#   * core extraction      — each shard scans its CO slot chunk for the
-#     θ ≥ ε prefix boundary; one pmin merges the boundary, one pmax merges
-#     the scattered core mask.
+#   * core extraction      — θ(v, μ) is one NO slot per vertex: the shard
+#     holding it reads it, one pmax merges (no scatter).
 #   * ε-similar filtering  — purely shard-local (each shard owns its edges).
 #   * connectivity         — all-reduced label propagation
-#     (:func:`connected_components_allreduce`): scatter-min locally,
-#     pmin-merge, pointer-jump on the replicated labels.
-#   * border attachment    — local scatter-max/min + pmax/pmin merges.
+#     (:func:`repro.core.connectivity.connected_components`): a local
+#     min over each row's slots in this shard, pmin-merge, pointer-jump on
+#     the replicated labels.
+#   * border attachment    — local row max/min + pmax/pmin merges.
 #
 # Every merge is an associative min/max, so each round reproduces the
-# single-device scatter exactly → results are bit-identical to
+# single-device reduction exactly → results are bit-identical to
 # ``core.query_batch`` (asserted in tests/test_distributed_query.py).
 
 
@@ -208,85 +229,52 @@ def query_mesh(n_shards: int | None = None, axis: str = "data") -> Mesh:
 @functools.partial(
     jax.jit, static_argnames=("n", "max_cdeg", "mesh", "axis"))
 def _sharded_query_batch(
-    eu, ev, esim, emask,            # edge-sized, padded to k·⌈E/k⌉
-    co_vertex, co_theta, co_idx,    # CO-slot-sized, padded likewise
-    co_offsets, mus, epss,          # replicated (small / parameter vectors)
+    eu, ev, esim, esim_t, emask,    # slot-sized, padded to k·⌈E/k⌉
+    epos, elast,                    # per-shard row layout
+    no_sims,                        # NO-slot-sized, padded likewise
+    offsets_c, cdeg, mus, epss,     # replicated (O(n) / parameter vectors)
     *, n: int, max_cdeg: int, mesh: Mesh, axis: str,
 ):
-    big_idx = jnp.int32(2 ** 30)
+    from repro.core.query import cluster_labels
 
-    def one(mu, eps, eu, ev, esim, emask, co_v, co_t, co_i, co_off):
+    steps = row_steps(max_cdeg - 1)
+
+    def one(mu, eps, eu, ev, esim, esim_t, emask, epos, elast, no_s):
         mu = jnp.asarray(mu, jnp.int32)
         eps = jnp.asarray(eps, jnp.float32)
 
-        # ---- cores: CO[μ] prefix with θ ≥ ε, slots sharded ----
-        lo = co_off[jnp.clip(mu, 0, max_cdeg)]
-        hi = co_off[jnp.clip(mu + 1, 0, max_cdeg + 1)]
-        in_seg = (co_i >= lo) & (co_i < hi)
-        below = in_seg & (co_t < eps)
-        local_first = jnp.min(jnp.where(below, co_i, big_idx))
-        first_below = jax.lax.pmin(local_first, axis)
-        first_below = jnp.where(first_below == big_idx, hi, first_below)
-        core_slots = in_seg & (co_i < first_below)
-        local_mask = (
-            jnp.zeros((n,), jnp.int32)
-            .at[co_v]
-            .max(core_slots.astype(jnp.int32), mode="drop")
-        )
-        is_core = jax.lax.pmax(local_mask, axis) > 0
-        is_core = is_core & (mu >= 2) & (mu <= max_cdeg)
+        # ---- cores: θ(v, μ) from the NO slot this shard holds ----
+        chunk = no_s.shape[0]
+        slot = offsets_c[:-1] + (mu - 1) - jax.lax.axis_index(axis) * chunk
+        mine = (slot >= 0) & (slot < chunk)
+        local = jnp.where(mine, no_s[jnp.clip(slot, 0, chunk - 1)],
+                          -jnp.inf)
+        theta = jax.lax.pmax(local, axis)
+        is_core = (mu >= 2) & (cdeg >= mu) & (theta >= eps)
 
-        # ---- ε-similar half-edges incident on cores (shard-local) ----
-        sim_ok = (esim >= eps) & emask
-        core_u = is_core[eu]
-        core_v = is_core[ev]
-        core_core = sim_ok & core_u & core_v
-
-        labels0 = connected_components_allreduce(
-            n, eu, ev, core_core, is_core, axis)
-        labels = jnp.where(is_core, labels0, jnp.int32(-1))
-
-        # ---- border attachment (scatter-max σ, tie to lower core id) ----
-        border_edge = sim_ok & core_u & ~core_v
-        neg = jnp.float32(-1.0)
-        local_best = (
-            jnp.full((n,), neg)
-            .at[ev]
-            .max(jnp.where(border_edge, esim, neg), mode="drop")
-        )
-        best_sim = jax.lax.pmax(local_best, axis)
-        tie = border_edge & (esim >= best_sim[ev]) & (best_sim[ev] > neg)
-        big = jnp.int32(n)
-        local_core = (
-            jnp.full((n,), big)
-            .at[ev]
-            .min(jnp.where(tie, eu, big), mode="drop")
-        )
-        best_core = jax.lax.pmin(local_core, axis)
-        has_border = best_core < big
-        border_label = labels0[jnp.clip(best_core, 0, n - 1)]
-        labels = jnp.where(~is_core & has_border, border_label, labels)
-
-        n_clusters = jnp.sum(is_core & (labels == jnp.arange(n)))
+        rows = RowLayout(epos, elast.reshape(-1), steps)
+        labels, n_clusters = cluster_labels(
+            n, is_core, eu, ev, esim, esim_t, eps, rows, emask=emask,
+            axis_name=axis)
         return labels, is_core, n_clusters
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P(axis),
-                  P(axis), P(axis), P(axis),
-                  P(None), P(None), P(None)),
+        in_specs=(P(axis),) * 6 + (P(axis, None), P(axis))
+        + (P(None),) * 4,
         out_specs=(P(None), P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
-    def _shard(eu, ev, esim, emask, co_v, co_t, co_i, co_off, mus, epss):
+    def _shard(eu, ev, esim, esim_t, emask, epos, elast, no_s,
+               offsets_c, cdeg, mus, epss):
         return jax.vmap(
-            lambda m, e: one(m, e, eu, ev, esim, emask, co_v, co_t, co_i,
-                             co_off)
+            lambda m, e: one(m, e, eu, ev, esim, esim_t, emask, epos,
+                             elast, no_s)
         )(mus, epss)
 
-    return _shard(eu, ev, esim, emask, co_vertex, co_theta, co_idx,
-                  co_offsets, mus, epss)
+    return _shard(eu, ev, esim, esim_t, emask, epos, elast, no_sims,
+                  offsets_c, cdeg, mus, epss)
 
 
 def _pad_host(arr, total: int, fill, dtype) -> np.ndarray:
@@ -303,7 +291,7 @@ class ShardedQueryPlan:
     """Padded, device-placed operands for repeated sharded queries over one
     (index, graph, mesh) triple.
 
-    Padding and concatenating the O(m) edge/CO-slot arrays is per-*plan*
+    Padding and concatenating the O(m) edge/NO-slot arrays is per-*plan*
     work, not per-*query* work: the serve engine answers a flush every few
     milliseconds against a fixed index, so it builds the plan once at
     registration and every device call is just the jitted shard_map
@@ -311,14 +299,16 @@ class ShardedQueryPlan:
     a throwaway plan for one-shot callers.
 
     Ragged edge counts are padded host-side to a multiple of the axis size;
-    padding edges carry ``emask=False`` and padded CO slots sit outside
-    every [lo, hi) segment, so they never contribute.
+    padding edges carry ``emask=False`` and sit past every row's last
+    slot, and padded NO slots are never a vertex's θ slot, so they never
+    contribute. The plan also carries each slot's reverse-slot σ and the
+    per-shard row layout (:class:`repro.core.connectivity.RowLayout`).
 
     :meth:`refresh` derives a successor plan after an incremental index
     update: per-shard chunks of each O(m) operand are compared host-side
     (sha256 content digests — 32 bytes per chunk retained, not the O(m)
     padded arrays themselves) and only *mutated* partitions are re-placed
-    on device; unchanged shards — and the replicated CO offsets — adopt
+    on device; unchanged shards — and the replicated O(n) vectors — adopt
     the old plan's buffers (an incremental edit batch typically touches a
     handful of partitions, not all k). ``refresh`` is loop-free pure
     compute, so the live-update path runs it in the engine's offload
@@ -334,7 +324,9 @@ class ShardedQueryPlan:
     cost shows up next to the query latency it protects.
     """
 
-    _SHARDED = ("emask", "eu", "ev", "esim", "co_v", "co_t", "co_i")
+    _SHARDED = ("emask", "eu", "ev", "esim", "esim_t", "epos", "elast",
+                "no_sims")
+    _REPLICATED = ("offsets_c", "cdeg")
 
     def __init__(self, index, g: CSRGraph, mesh: Mesh, axis: str = "data",
                  *, registry=None,
@@ -351,17 +343,32 @@ class ShardedQueryPlan:
         repl = NamedSharding(mesh, P())
 
         ep = max(-(-max(g.m2, 1) // k) * k, k)   # edge slots per full array
-        m_co = index.co_vertex.shape[0]
-        cp = max(-(-max(m_co, 1) // k) * k, k)
+        chunk = ep // k
+        m_no = index.no_sims.shape[0]
+        cp = max(-(-max(m_no, 1) // k) * k, k)
+        offsets = np.asarray(g.offsets, np.int64)
+        eu = np.asarray(g.edge_u, np.int64)
+        nbrs = np.asarray(g.nbrs, np.int64)
+        esim = np.asarray(index.edge_sims, np.float32)
+        slot = np.arange(g.m2, dtype=np.int64)
+        # reverse slot of each half-edge: slots sorted by (v, u)
+        twin = np.lexsort((eu, nbrs))
+        # row layout per shard: a row cut by a shard boundary restarts at
+        # the shard's first slot; each vertex's last slot inside a shard
+        pos = slot - np.maximum(offsets[eu], slot - slot % chunk)
+        lo_s = np.arange(k, dtype=np.int64)[:, None] * chunk
+        first = np.maximum(offsets[None, :-1], lo_s)
+        last = np.minimum(offsets[None, 1:], lo_s + chunk) - 1
+        elast = np.where(last >= first, last - lo_s, -1)
         host = {   # transient: placed on device, digested, then dropped
             "emask": np.arange(ep) < g.m2,
-            "eu": _pad_host(g.edge_u, ep, 0, np.int32),
-            "ev": _pad_host(g.nbrs, ep, 0, np.int32),
-            "esim": _pad_host(index.edge_sims, ep, 0.0, np.float32),
-            "co_v": _pad_host(index.co_vertex, cp, 0, np.int32),
-            "co_t": _pad_host(index.co_theta, cp, 0.0, np.float32),
-            "co_i": _pad_host(np.arange(m_co, dtype=np.int32), cp, 2 ** 30,
-                              np.int32),
+            "eu": _pad_host(eu, ep, 0, np.int32),
+            "ev": _pad_host(nbrs, ep, 0, np.int32),
+            "esim": _pad_host(esim, ep, 0.0, np.float32),
+            "esim_t": _pad_host(esim[twin], ep, 0.0, np.float32),
+            "epos": _pad_host(pos, ep, 0, np.int32),
+            "elast": elast.astype(np.int32),
+            "no_sims": _pad_host(index.no_sims, cp, 0.0, np.float32),
         }
         self._chunk_digests: dict = {}
         stats = {"chunks": k * len(self._SHARDED), "reused": 0, "placed": 0,
@@ -371,18 +378,20 @@ class ShardedQueryPlan:
             setattr(self, name, arr)
             stats["reused"] += reused
             stats["placed"] += k - reused
-        # the replicated CO segment offsets diff the same way the sharded
-        # chunks do: unchanged content adopts the predecessor's buffer
-        co_off_host = np.asarray(index.co_offsets)
-        self._co_off_digest = (co_off_host.shape,
-                               hashlib.sha256(co_off_host.tobytes()).digest())
-        if (_reuse_from is not None and _reuse_from.mesh is self.mesh
-                and getattr(_reuse_from, "_co_off_digest", None)
-                == self._co_off_digest):
-            self.co_offsets = _reuse_from.co_offsets
-            stats["repl_reused"] = 1
-        else:
-            self.co_offsets = jax.device_put(index.co_offsets, repl)
+        # the replicated O(n) vectors diff the same way the sharded chunks
+        # do: unchanged content adopts the predecessor's buffer
+        self._repl_digests = {}
+        for name in self._REPLICATED:
+            h = np.asarray(getattr(index, name))
+            self._repl_digests[name] = (h.shape,
+                                        hashlib.sha256(h.tobytes()).digest())
+            if (_reuse_from is not None and _reuse_from.mesh is self.mesh
+                    and _reuse_from._repl_digests.get(name)
+                    == self._repl_digests[name]):
+                setattr(self, name, getattr(_reuse_from, name))
+                stats["repl_reused"] += 1
+            else:
+                setattr(self, name, jax.device_put(jnp.asarray(h), repl))
         self.last_refresh = stats
         if self._registry is not None:
             self._registry.inc("sharded.chunks_reused", stats["reused"])
@@ -450,9 +459,9 @@ class ShardedQueryPlan:
         mus = jnp.atleast_1d(jnp.asarray(mus, jnp.int32))
         epss = jnp.atleast_1d(jnp.asarray(epss, jnp.float32))
         labels, is_core, n_clusters = _sharded_query_batch(
-            self.eu, self.ev, self.esim, self.emask,
-            self.co_v, self.co_t, self.co_i,
-            self.co_offsets, mus, epss,
+            self.eu, self.ev, self.esim, self.esim_t, self.emask,
+            self.epos, self.elast, self.no_sims,
+            self.offsets_c, self.cdeg, mus, epss,
             n=self.n, max_cdeg=self.max_cdeg, mesh=self.mesh,
             axis=self.axis)
         return ClusterResult(labels=labels, is_core=is_core,

@@ -176,6 +176,7 @@ def power_law_graph(
     seed: int = 0,
     weighted: bool = False,
     hub_degree: int = 0,
+    max_degree: int = 0,
 ) -> CSRGraph:
     """Chung–Lu-style power-law graph (host-side numpy).
 
@@ -185,12 +186,30 @@ def power_law_graph(
     to that many distinct random vertices, forcing one hub with
     deg ≫ median (the skew case the degree-bucketed similarity engine
     exists for; a dense-padded layout would pay O(n·hub_degree)).
+
+    ``max_degree > 0`` caps every vertex's expected degree there (the
+    excess weight spreads over the uncapped vertices): uncapped, α = 2.1
+    at n = 2²⁰ gives vertex 0 an expected degree in the hundreds of
+    thousands, far past what social graphs of that size show.
+
+    ``n·avg_degree`` endpoint pairs are drawn before self-loops and
+    duplicates drop, so the mean degree comes out at up to
+    ``2·avg_degree`` (less where skew makes many duplicates).
     """
     rng = np.random.default_rng(seed)
     ranks = np.arange(1, n + 1, dtype=np.float64)
     w = ranks ** (-1.0 / (alpha - 1.0))
     p = w / w.sum()
     target_m = int(n * avg_degree / 2)
+    if max_degree > 0:
+        # expected degree of i is 2 · (2·target_m draws) · p_i
+        cap = max_degree / (4.0 * target_m)
+        if cap * n <= 1.0:
+            raise ValueError(f"max_degree={max_degree} is below the mean "
+                             "degree: no capped weights exist")
+        while p.max() > cap * (1 + 1e-9):
+            p = np.minimum(p, cap)
+            p = p / p.sum()
     u = rng.choice(n, size=3 * target_m, p=p)
     v = rng.choice(n, size=3 * target_m, p=p)
     e = np.stack([u, v], axis=1)

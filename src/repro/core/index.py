@@ -21,7 +21,7 @@ around jit-compiled kernels; every array op is a bulk-parallel primitive
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,38 +62,66 @@ class ScanIndex:
         return jnp.where(valid, theta, -jnp.inf)
 
 
+def _desc_key(sims: jax.Array) -> jax.Array:
+    """int32 sort key ascending in −σ: σ ∈ [0, 1] is a non-negative float,
+    so its bit pattern orders like its value (−0.0 folds onto +0.0)."""
+    return -jnp.maximum(jax.lax.bitcast_convert_type(sims, jnp.int32), 0)
+
+
 @jax.jit
 def _build_orders(offsets, edge_u, nbrs, sims, n_arr):
-    """Global sorts for NO and CO. n_arr = jnp.arange(n)."""
+    """Global sorts for NO and CO. n_arr = jnp.arange(n).
+
+    ``edge_u``/``nbrs``/``sims`` may carry padding slots past the real
+    half-edges, with source row ``n``: they sort after every real slot of
+    both orders, so the caller slices them off and a padded slot count
+    (:func:`_slot_capacity`) lets nearby graph sizes share one compile.
+
+    Both orders come from two-key stable sorts over int32 keys. The closed
+    slots are the n self slots followed by the CSR's half-edges (ascending
+    neighbors within each row), so a stable (row, −σ) sort yields NO's
+    (row asc, σ desc, self first, nbr asc); a stable (μ, −θ) sort of the NO
+    slots then yields CO's (μ asc, θ desc, v asc), since NO is row-major.
+    Fewer, integer keys keep the TPU compile of the sorts short."""
     n = n_arr.shape[0]
-    # ---- closed slot arrays: n self slots + m2 edge slots ----
+    m2c = edge_u.shape[0] + n
+    # ---- closed slot arrays: n self slots + the (padded) edge slots ----
     rows = jnp.concatenate([n_arr, edge_u])
     nbrs_c = jnp.concatenate([n_arr, nbrs])
     sims_c = jnp.concatenate([jnp.ones((n,), jnp.float32), sims])
-    not_self = jnp.concatenate(
-        [jnp.zeros((n,), jnp.int32), jnp.ones((edge_u.shape[0],), jnp.int32)]
-    )
-    # one global sort: row asc, σ desc, self first, nbr asc
-    perm = jnp.lexsort((nbrs_c, not_self, -sims_c, rows))
+    is_self = jnp.arange(m2c) < n
+    # one global sort: row asc, σ desc; ties keep self-first, nbr asc
+    _, _, perm = jax.lax.sort(
+        (rows, _desc_key(sims_c), jnp.arange(m2c, dtype=jnp.int32)),
+        num_keys=2, is_stable=True)
     no_nbrs = nbrs_c[perm]
     no_sims = sims_c[perm]
-    no_self = not_self[perm] == 0
+    no_self = is_self[perm]
     rows_sorted = rows[perm]
 
     cdeg = jnp.diff(offsets) + 1
     offsets_c = offsets + jnp.arange(n + 1, dtype=offsets.dtype)
 
     # ---- core order: every slot with position μ ≥ 2 inside its row ----
-    m2c = no_nbrs.shape[0]
     mu_of_slot = jnp.arange(m2c, dtype=jnp.int32) - offsets_c[rows_sorted] + 1
-    is_co = mu_of_slot >= 2
-    # key sort: μ asc, θ desc, v asc; inactive slots pushed to the end
+    is_co = (mu_of_slot >= 2) & (rows_sorted < n)
+    # key sort: μ asc, θ desc; ties keep NO's row order (v asc); inactive
+    # slots pushed to the end
     mu_key = jnp.where(is_co, mu_of_slot, jnp.int32(2**30))
-    perm2 = jnp.lexsort((rows_sorted, -no_sims, mu_key))
-    co_vertex = rows_sorted[perm2][: m2c - n]
-    co_theta = no_sims[perm2][: m2c - n]
-    co_mu = mu_key[perm2][: m2c - n]
+    _, _, perm2 = jax.lax.sort(
+        (mu_key, _desc_key(no_sims), jnp.arange(m2c, dtype=jnp.int32)),
+        num_keys=2, is_stable=True)
+    co_vertex = rows_sorted[perm2]
+    co_theta = no_sims[perm2]
+    co_mu = mu_key[perm2]
     return (offsets_c, no_nbrs, no_sims, no_self, cdeg, co_vertex, co_theta, co_mu)
+
+
+def _slot_capacity(m: int) -> int:
+    """``m`` rounded up to one of 16 steps per octave (≤ 6.25 % padding),
+    so a graph edited by a few edges keeps its compiled global sorts."""
+    step = 1 << max(m.bit_length() - 5, 0)
+    return -(-max(m, 1) // step) * step
 
 
 def build_index(
@@ -122,9 +150,17 @@ def build_index(
     sims = jnp.clip(sims.astype(jnp.float32), 0.0, 1.0)
 
     n_arr = jnp.arange(g.n, dtype=jnp.int32)
+    pad = _slot_capacity(g.m2) - g.m2
     (offsets_c, no_nbrs, no_sims, no_self, cdeg, co_vertex, co_theta, co_mu) = (
-        _build_orders(g.offsets, g.edge_u, g.nbrs, sims, n_arr)
+        _build_orders(g.offsets,
+                      jnp.pad(g.edge_u, (0, pad), constant_values=g.n),
+                      jnp.pad(g.nbrs, (0, pad), constant_values=g.n),
+                      jnp.pad(sims, (0, pad)), n_arr)
     )
+    m2c = g.m2 + g.n
+    no_nbrs, no_sims, no_self = no_nbrs[:m2c], no_sims[:m2c], no_self[:m2c]
+    co_vertex, co_theta, co_mu = (
+        co_vertex[:g.m2], co_theta[:g.m2], co_mu[:g.m2])
     max_cdeg = int(np.asarray(cdeg).max()) if g.n else 1
     # segment starts per μ; CO[μ] = co_vertex[co_offsets[μ] : co_offsets[μ+1]]
     counts = np.bincount(np.asarray(co_mu), minlength=max_cdeg + 1)
@@ -145,61 +181,20 @@ def build_index(
         cdeg=cdeg,
         edge_sims=sims,
         n=g.n,
-        m2c=g.m2 + g.n,
+        m2c=m2c,
         max_cdeg=max_cdeg,
     )
 
 
-def co_core_prefix(index: ScanIndex, mu, eps) -> Tuple[jax.Array, jax.Array]:
-    """(lo, end): the CO[μ] slot range [lo, end) holding every core for
-    (μ, ε), found in **O(log m)** per query.
-
-    The CO slot arrays are globally sorted by the packed key (μ asc,
-    −θ asc, v asc); ``co_offsets`` resolves the μ component exactly, so the
-    prefix boundary is a searchsorted for −ε over the −θ component inside
-    [lo, hi) — implemented as a branchless traced-bound binary search
-    (``jnp.searchsorted`` cannot take traced slice bounds). This replaces
-    the old masked arange-argmax, which scanned all m2 CO slots per query.
-    """
-    mu = jnp.asarray(mu, jnp.int32)
-    eps = jnp.asarray(eps, jnp.float32)
-    lo = index.co_offsets[jnp.clip(mu, 0, index.max_cdeg)].astype(jnp.int32)
-    hi = index.co_offsets[jnp.clip(mu + 1, 0, index.max_cdeg + 1)].astype(
-        jnp.int32)
-    m_co = index.co_theta.shape[0]
-    if m_co == 0:                       # edgeless graph: CO is empty
-        return lo, lo
-
-    def body(_, lohi):
-        lo_, hi_ = lohi
-        mid = (lo_ + hi_) // 2
-        theta = index.co_theta[jnp.clip(mid, 0, max(m_co - 1, 0))]
-        keep_hi = (mid < hi_) & (theta >= eps)     # mid in the θ ≥ ε prefix
-        return (jnp.where(keep_hi, mid + 1, lo_), jnp.where(keep_hi, hi_, mid))
-
-    steps = max(int(m_co).bit_length(), 1)
-    lo_f, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    return lo, lo_f
-
-
 def get_cores(index: ScanIndex, mu: int, eps: float) -> jax.Array:
-    """bool[n] core mask via the CO[μ] prefix (paper Algorithm 3).
+    """bool[n] core mask (paper Algorithm 3).
 
-    CO[μ] is θ-descending, so cores are the prefix with θ ≥ ε — the
-    boundary comes from :func:`co_core_prefix`'s O(log m) packed-key
-    search; scattering the prefix slots to a vertex mask is O(m2)
-    elementwise work with no reductions (the old path burned three full
-    masked reductions — any/argmax — per query, per vmap lane).
-    """
+    v is a core iff θ(v, μ) ≥ ε, and θ(v, μ) is the μ-th slot of NO[v]:
+    one gather per vertex (O(n)). This is the set the θ ≥ ε prefix of
+    CO[μ] holds, read point-wise instead of scattered from the CO slots (a
+    scatter over m2 slots per query, which a TPU serializes).
+    ``cdeg ≥ μ`` implies μ ≤ max_cdeg."""
     mu = jnp.asarray(mu, jnp.int32)
     eps = jnp.asarray(eps, jnp.float32)
-    lo, first_below = co_core_prefix(index, mu, eps)
-    idx = jnp.arange(index.co_vertex.shape[0], dtype=jnp.int32)
-    core_slots = (idx >= lo) & (idx < first_below)
-    mask = (
-        jnp.zeros((index.n,), jnp.int32)
-        .at[index.co_vertex]
-        .max(core_slots.astype(jnp.int32), mode="drop")
-    ) > 0
-    valid_mu = (mu >= 2) & (mu <= index.max_cdeg)
-    return mask & valid_mu
+    theta = index.core_threshold(mu)
+    return (mu >= 2) & (index.cdeg >= mu) & (theta >= eps)

@@ -4,9 +4,8 @@ Per-user traffic asks "what is *my* community at (μ, ε)?" — answering it
 with the global ``query()`` materializes all n labels to read one row.
 Following the local-graph-clustering framing (Shun et al., PAPERS.md),
 the GS*-Index already supports output-sensitive resolution: core
-membership is one θ-slot probe per vertex (the ``co_core_prefix``
-boundary expressed point-wise — v is a (μ, ε)-core iff the μ-th entry
-of NO[v], i.e. ``core_threshold(v, μ)``, is ≥ ε), and the seed's
+membership is one θ-slot probe per vertex (v is a (μ, ε)-core iff the
+μ-th entry of NO[v], i.e. ``core_threshold(v, μ)``, is ≥ ε), and the seed's
 cluster is the connected component of its attachment core in the
 ε-similar core–core graph, reachable by frontier expansion over NO row
 prefixes. Work scales with the *output cluster*, not with n.
@@ -92,10 +91,8 @@ def _is_core(index: ScanIndex, v, mu, eps):
     """(μ, ε)-core test for vertex ids ``v`` (any shape; out-of-range ids
     test non-core). One θ-slot probe per vertex: NO rows are σ-descending
     with the self slot pinned first, so the μ-th row entry *is* the core
-    threshold θ(v, μ) — the same boundary ``co_core_prefix`` binary-
-    searches globally, evaluated point-wise. Bit-identical to
-    ``get_cores(index, mu, eps)[v]`` (μ > max_cdeg ⇒ cdeg < μ for all v,
-    so the ``valid_mu`` clamp there is implied by the cdeg guard here)."""
+    threshold θ(v, μ). Bit-identical to ``get_cores(index, mu, eps)[v]``,
+    which evaluates the same test for every vertex."""
     vc = jnp.clip(v, 0, index.n - 1)
     slot = index.offsets_c[vc].astype(jnp.int32) + (mu - 1)
     theta = index.no_sims[jnp.clip(slot, 0, index.m2c - 1)]

@@ -36,23 +36,34 @@ import numpy as np
 
 from repro.core.graph import CSRGraph
 from repro.core import similarity as sim_mod
+from repro.kernels.hamming import hamming_sigma
+from repro.kernels.ref import hamming_distance_ref
 
 
 # --------------------------------------------------------------------------
 # SimHash
 # --------------------------------------------------------------------------
+# element budget of one sketch chunk's (half-edges × samples) projection
+# gather: 2²⁸ float32 = 1 GiB of transient device memory
+SKETCH_CHUNK_ELEMS = 1 << 28
+
+
 def simhash_sketches(g: CSRGraph, samples: int, key: jax.Array,
-                     *, chunk: int = 512) -> jax.Array:
+                     *, chunk: Optional[int] = None) -> jax.Array:
     """Packed sketches uint32[n, ceil(k/32)] of closed weighted neighborhoods.
 
-    ``chunk`` bounds the (n, chunk) gaussian working set; it is a *memory*
-    knob only. Each 32-sample word derives its projections from
+    ``chunk`` samples are projected per pass, which bounds the (m2, chunk)
+    gathered projections; it is a *memory* knob only, by default the
+    largest multiple of 32 (at most 512) within ``SKETCH_CHUNK_ELEMS``.
+    Each 32-sample word derives its projections from
     ``fold_in(key, word_index)``, so the sketch bits — and therefore σ̂ and
     every downstream index fingerprint — are invariant to the chunking.
     (The old per-chunk ``fold_in(key, w0)`` keyed the randomness on the
     chunk boundary itself: changing the chunk width silently changed every
     sketch.)
     """
+    if chunk is None:
+        chunk = min(max(SKETCH_CHUNK_ELEMS // max(g.m2, 1) // 32, 1), 16) * 32
     if chunk % 32 != 0 or chunk <= 0:
         raise ValueError(f"chunk must be a positive multiple of 32: {chunk}")
     k_pad = (samples + 31) // 32 * 32
@@ -82,15 +93,18 @@ def _simhash_chunk(edge_u, nbrs, wgts, key, word0, n, kw, valid):
     return jnp.sum(bits.astype(jnp.uint32) * weights, axis=-1, dtype=jnp.uint32)
 
 
-@functools.partial(jax.jit, static_argnames=("samples",))
 def simhash_edge_similarity(
     sketches: jax.Array, eu: jax.Array, ev: jax.Array, samples: int
 ) -> jax.Array:
-    """cos(π·hamming/k) per edge from packed sketches."""
-    x = jnp.bitwise_xor(sketches[eu], sketches[ev])
-    diff = jnp.sum(jax.lax.population_count(x), axis=-1).astype(jnp.float32)
-    theta = jnp.pi * diff / samples
-    return jnp.cos(theta)
+    """cos(π·hamming/k) per edge from packed sketches: the ``hamming`` op's
+    ref lane, ending in the :func:`~repro.kernels.hamming.hamming_sigma`
+    epilogue that every lane shares."""
+    return hamming_sigma(_hamming_distance(sketches, eu, ev), samples)
+
+
+@jax.jit
+def _hamming_distance(sketches, eu, ev):
+    return hamming_distance_ref(sketches[eu], sketches[ev])
 
 
 # --------------------------------------------------------------------------
@@ -196,10 +210,11 @@ def approximate_similarities(
     sparse jnp path (its bits define the approximate fingerprint); the
     sketch *comparison* resolves its lane through the execution policy —
     the ``hamming`` op's Pallas lanes consume the exact same sketches and
-    reproduce the ``ref`` comparison bit-for-bit on host backends (the
-    XOR/popcount sum is integer-exact; the cos epilogue is the same
-    elementwise expression), so lane choice never moves a fingerprint."""
-    from repro.backend.policy import LANE_REF, default_policy
+    reproduce the ``ref`` comparison bit-for-bit on every backend (each
+    lane yields the integer-exact XOR/popcount distance, and all of them
+    share one jitted cos epilogue), so lane choice never moves a
+    fingerprint."""
+    from repro.backend.policy import default_policy
 
     pol = policy if policy is not None else default_policy()
     if key is None:
@@ -207,15 +222,11 @@ def approximate_similarities(
     if method == "simhash":
         if measure != "cosine":
             raise ValueError("simhash approximates cosine similarity")
+        from repro.kernels import ops
         sk = simhash_sketches(g, samples, key)
-        lane = pol.lane("hamming")
-        if lane == LANE_REF:
-            pol.note("hamming", lane)
-            approx = simhash_edge_similarity(sk, g.edge_u, g.nbrs, samples)
-        else:
-            from repro.kernels import ops
-            approx = ops.simhash_edge_similarity_kernel(
-                sk, g.edge_u, g.nbrs, samples, policy=pol, lane=lane)
+        approx = ops.simhash_edge_similarity_kernel(
+            sk, g.edge_u, g.nbrs, samples, policy=pol,
+            lane=pol.lane("hamming"))
         thr = samples
     elif method in ("minhash", "kpartition"):
         if measure != "jaccard":

@@ -98,7 +98,7 @@ from repro.backend.padding import (
     pow2ceil as _pow2ceil,
 )
 from repro.backend.policy import (
-    LANE_INTERPRET, LANE_REF, ExecutionPolicy, default_policy,
+    LANE_COMPILED, LANE_INTERPRET, LANE_REF, ExecutionPolicy, default_policy,
 )
 from repro.core.graph import CSRGraph, to_dense
 
@@ -110,6 +110,8 @@ BUCKET_FLOOR = 8
 HUB_TILE = 2048
 # per-chunk element budget for the transient gathered row matrices
 CHUNK_ELEMS = 1 << 22
+# fewest edges per compiled bucket_probe chunk: one full 128-lane row
+KERNEL_LANE_EDGES = 128
 
 # legacy dense-padded quantum (kept for the benchmark baseline path)
 PAD_WIDTH_QUANTUM = 8
@@ -169,12 +171,18 @@ class SimilarityPlan:
     deg: np.ndarray      # int64[n] open degrees (host routing key)
     norms: jax.Array     # float32[n]
     cdeg: jax.Array      # int32[n]
-    # observability: kernel groups the most recent edge_sims call routed to
-    # (stat slot, not identity; written via object.__setattr__)
-    last_groups: int = 0
+    # observability: the kernel groups the most recent edge_sims call routed
+    # to, each as (probe width, target width, lane, edges) (stat slot, not
+    # identity; written via object.__setattr__)
+    last_group_shapes: Tuple[Tuple[int, int, str, int], ...] = ()
     # observability: what the :meth:`apply` that produced this plan did
     # (None for plans built from scratch)
     last_apply: Optional[dict] = None
+
+    @property
+    def last_groups(self) -> int:
+        """Kernel groups the most recent edge_sims call routed to."""
+        return len(self.last_group_shapes)
 
     # -- construction -------------------------------------------------------
     @staticmethod
@@ -440,9 +448,9 @@ class SimilarityPlan:
         order = np.argsort(keys, kind="stable")
         bounds = np.flatnonzero(np.diff(keys[order])) + 1
         groups = np.split(order, bounds)
-        object.__setattr__(self, "last_groups", len(groups))
 
         out = np.empty(total, out_dt)
+        shapes = []
         for idx in groups:
             cp = int(self.vclass[pu[idx[0]]])
             ct = int(self.vclass[pv[idx[0]]])
@@ -452,8 +460,13 @@ class SimilarityPlan:
             te = st * self.widths[ct]
             lane = pol.lane("bucket_probe", width=pe)
             pol.note("bucket_probe", lane)
+            shapes.append((pe, te, lane, len(idx)))
             cap = max(CHUNK_ELEMS // max(pe + te, 1), 1)
             cap = 1 << (cap.bit_length() - 1)
+            if lane == LANE_COMPILED:
+                # the kernel lays edges on the 128 lanes: a narrower chunk
+                # would leave lanes idle on the widest (hub) groups
+                cap = max(cap, KERNEL_LANE_EDGES)
             csize = min(_pow2_bucket(len(idx)), max(min(chunk, cap), 1))
             if lane == LANE_INTERPRET:
                 # interpret-mode grids unroll at trace time: bound the
@@ -493,6 +506,7 @@ class SimilarityPlan:
                         bt=pol.profile.probe_bt,
                         interpret=pol.interpret(lane))
                 out[sub] = np.asarray(res)[: len(sub)]
+        object.__setattr__(self, "last_group_shapes", tuple(shapes))
         return jnp.asarray(out)
 
 
@@ -612,19 +626,17 @@ def _bucket_sims_chunk(p0, pt, t0, tt, eu, ev, ew,
                              sp, st, measure)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "sp", "st", "measure", "be", "bt", "interpret"))
-def _bucket_sims_chunk_pallas(p0, pt, t0, tt, eu, ev, ew,
-                              p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
-                              *, sp: int, st: int, measure: str,
-                              be: int, bt: int, interpret: bool):
+def _bucket_sims_pallas_core(p0, pt, t0, tt, eu, ev, ew,
+                             p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
+                             sp: int, st: int, measure: str,
+                             be: int, bt: int, interpret: bool):
     """The Pallas lane of one group chunk: gather the same tiled rows the
     jnp engine would, run the sorted-probe kernel
     (:mod:`repro.kernels.bucket_probe`) instead of searchsorted, and apply
     the shared epilogue. Unweighted shared dots/counts are small integers
     (exact in f32 under any accumulation order), so this lane is
     bit-identical to :func:`_bucket_sims_core`; weighted dots agree to
-    ULP."""
+    ULP. Shared with the shard_map path in :mod:`repro.core.distributed`."""
     from repro.kernels.bucket_probe import bucket_probe
     from repro.kernels.ops import probe_operands
 
@@ -638,6 +650,18 @@ def _bucket_sims_chunk_pallas(p0, pt, t0, tt, eu, ev, ew,
     e0 = eu.shape[0]
     return _sigma_epilogue(dot[:e0], cnt[:e0], eu, ev, ew, norms, cdeg,
                            measure)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sp", "st", "measure", "be", "bt", "interpret"))
+def _bucket_sims_chunk_pallas(p0, pt, t0, tt, eu, ev, ew,
+                              p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
+                              *, sp: int, st: int, measure: str,
+                              be: int, bt: int, interpret: bool):
+    """Jitted :func:`_bucket_sims_pallas_core` for one group chunk."""
+    return _bucket_sims_pallas_core(p0, pt, t0, tt, eu, ev, ew,
+                                    p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
+                                    sp, st, measure, be, bt, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 _EP_MESH = None
@@ -107,12 +106,12 @@ def ep_ffn(xf, router, w_gate, w_up, w_down, cfg):
         t_l = x_l.shape[0]
         return jax.lax.dynamic_slice_in_dim(combined, idx * t_l, t_l, axis=0)
 
-    return shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(dp_entry, None), P(None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None)),
         out_specs=P(dp_entry, None),
-        check_rep=False,
+        check_vma=False,
     )(xf, router, w_gate, w_up, w_down)

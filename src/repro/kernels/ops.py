@@ -30,7 +30,7 @@ from repro.kernels import ref as kref
 from repro.kernels.triangle_count import masked_gram
 from repro.kernels.bucket_probe import bucket_probe
 from repro.kernels.simhash import simhash_pack
-from repro.kernels.hamming import hamming_cosine
+from repro.kernels.hamming import hamming_distance, hamming_sigma
 from repro.kernels.flash_attention import flash_attention
 
 
@@ -80,16 +80,19 @@ def probe_operands(rows_p, w_p, rows_t, w_t, n: int, be: int, bt: int):
     similarity engine's Pallas lane).
 
     Sanitizes padding ids (probe → -1, target → -2 so pads never match),
-    pads the edge axis to ``be`` and the target width to ``bt`` (the
-    hub-row tile the kernel streams). Widens with the sentinel id ``n``
-    BEFORE sanitizing, so width padding becomes -2 like every other target
-    pad (0 would alias vertex id 0). Returns (ids_p, w_p, ids_t, w_t, bt).
+    pads the edge axis to ``be``, the target width to ``bt`` (the hub-row
+    tile the kernel streams) and the probe width to the kernel's probe
+    chunk ``min(P, bt)``. Widens with the sentinel id ``n`` BEFORE
+    sanitizing, so width padding becomes a pad id like every other (0
+    would alias vertex id 0). Returns (ids_p, w_p, ids_t, w_t, bt).
     """
     t = rows_t.shape[1]
     bt = min(bt, max(t, 1))
-    pad_w = (-t) % bt
-    rows_t = jnp.pad(rows_t, ((0, 0), (0, pad_w)), constant_values=n)
-    w_t = jnp.pad(w_t, ((0, 0), (0, pad_w)))
+    pc = min(rows_p.shape[1], bt)
+    rows_p = pad_to(rows_p, pc, (1,), value=n)
+    w_p = pad_to(w_p, pc, (1,))
+    rows_t = pad_to(rows_t, bt, (1,), value=n)
+    w_t = pad_to(w_t, bt, (1,))
     ids_p = jnp.where(rows_p < n, rows_p, -1).astype(jnp.int32)
     ids_t = jnp.where(rows_t < n, rows_t, -2).astype(jnp.int32)
     ids_p = pad_to(ids_p, be, (0,))
@@ -148,15 +151,9 @@ def simhash_sketches_kernel(
     if lane == LANE_REF:
         sk = kref.simhash_pack_ref(w, r)
     else:
-        sk = simhash_pack(w, r, bm=block, bs=128, bk=block,
+        sk = simhash_pack(w, r, bm=block, bk=block,
                           interpret=pol.interpret(lane))
     return sk[:n0, : (samples + 31) // 32]
-
-
-# jitted so the ref lane's cos lowers through the same compiler path as
-# the Pallas lanes — eager dispatch picks a different cos approximation
-# on CPU (1-ULP drift), which would break the lane bit-identity contract
-_hamming_ref_jit = jax.jit(kref.hamming_cosine_ref, static_argnums=2)
 
 
 def simhash_edge_similarity_kernel(
@@ -164,17 +161,20 @@ def simhash_edge_similarity_kernel(
     block: Optional[int] = None,
     *, policy: Optional[ExecutionPolicy] = None, lane: Optional[str] = None,
 ) -> jax.Array:
-    """σ̂ per edge from packed sketches via the hamming op."""
+    """σ̂ per edge from packed sketches via the hamming op. Every lane
+    yields the integer Hamming distance; the shared :func:`hamming_sigma`
+    epilogue turns it into σ̂, so lanes agree bit-for-bit on any chip."""
     pol, lane = _resolve(policy, "hamming", lane)
     block = block or pol.profile.hamming_block
     e0 = eu.shape[0]
+    su, sv = sketches[eu], sketches[ev]
     if lane == LANE_REF:
-        return _hamming_ref_jit(sketches[eu], sketches[ev], samples)
-    su = pad_to(sketches[eu], block, (0,))
-    sv = pad_to(sketches[ev], block, (0,))
-    out = hamming_cosine(su, sv, samples=samples, be=block,
-                         interpret=pol.interpret(lane))
-    return out[:e0]
+        diff = kref.hamming_distance_ref(su, sv)
+    else:
+        diff = hamming_distance(pad_to(su, block, (0,)),
+                                pad_to(sv, block, (0,)), be=block,
+                                interpret=pol.interpret(lane))[:e0]
+    return hamming_sigma(diff, samples)
 
 
 def attention(

@@ -28,10 +28,10 @@ def simhash_pack_ref(w: jax.Array, r: jax.Array) -> jax.Array:
     return jnp.sum(lanes * weights[None, None, :], axis=-1, dtype=jnp.uint32)
 
 
-def hamming_cosine_ref(sk_u: jax.Array, sk_v: jax.Array, samples: int) -> jax.Array:
+def hamming_distance_ref(sk_u: jax.Array, sk_v: jax.Array) -> jax.Array:
+    """Differing bits per row of packed uint32 sketches, int32[e]."""
     x = jnp.bitwise_xor(sk_u, sk_v)
-    diff = jnp.sum(jax.lax.population_count(x), axis=-1).astype(jnp.float32)
-    return jnp.cos(jnp.pi * diff / samples)
+    return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
 
 
 def flash_attention_ref(

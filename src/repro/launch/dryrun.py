@@ -239,8 +239,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             compiled = lowered.compile()
             t2 = time.time()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):   # jax 0.4.x wraps in a list
-                ca = ca[0] if ca else {}
             ma = compiled.memory_analysis()
             hlo = compiled.as_text()
             coll = parse_collectives(hlo)

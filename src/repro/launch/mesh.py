@@ -10,14 +10,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax ≥ 0.5 takes axis_types=(AxisType.Auto, ...); 0.4.x has no axis
-    # types (every axis is implicitly auto). Support both.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -778,6 +778,8 @@ def main():
         # heavier repro imports are deferred into the command functions
         from repro.core.distributed import force_host_devices
         force_host_devices(args.shards)
+    from repro.backend.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args.fn(args)
 
 

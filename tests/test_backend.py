@@ -141,6 +141,53 @@ def test_forced_lane_clamps_to_registered_lanes(monkeypatch):
     assert pol.kernel_lane("query") == LANE_REF
 
 
+@pytest.mark.parametrize("via", ["env", "constructor"])
+def test_forced_interpret_lane_refused_on_tpu(monkeypatch, via):
+    """On a TPU a forced pallas-interpret lane would emulate every kernel
+    on the host while the chip idles: the policy raises instead."""
+    from unittest import mock
+    if via == "env":
+        monkeypatch.setenv("REPRO_LANE", LANE_INTERPRET)
+        pol = ExecutionPolicy()
+    else:
+        monkeypatch.delenv("REPRO_LANE", raising=False)
+        pol = ExecutionPolicy(forced_lane=LANE_INTERPRET)
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        for resolve in (lambda: pol.lane("bucket_probe", width=1 << 20),
+                        lambda: pol.kernel_lane("hamming"), pol.describe):
+            with pytest.raises(RuntimeError, match="pallas-interpret"):
+                resolve()
+        if via == "constructor":
+            # the other lanes stay selectable on the chip
+            assert ExecutionPolicy(forced_lane=LANE_REF).lane(
+                "bucket_probe") == LANE_REF
+    # off the chip the same policy keeps its test lane
+    with mock.patch("jax.default_backend", return_value="cpu"):
+        assert pol.lane("bucket_probe") == LANE_INTERPRET
+
+
+def test_compile_cache_dir_placed_from_outside_or_fixed(monkeypatch,
+                                                        tmp_path):
+    from repro.backend import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        outside = str(tmp_path / "cache")
+        monkeypatch.setenv(cc.ENV_DIR, outside)
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert cc.enable_compile_cache() == outside
+        # JAX reads the variable itself; the helper sets no other path
+        assert jax.config.jax_compilation_cache_dir is None
+
+        monkeypatch.delenv(cc.ENV_DIR)
+        first = cc.enable_compile_cache()
+        assert first == cc.compile_cache_dir() == cc.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == first
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_constructor_lane_beaten_by_env(monkeypatch):
     monkeypatch.delenv("REPRO_LANE", raising=False)
     pol = ExecutionPolicy(forced_lane=LANE_REF)

@@ -9,7 +9,7 @@ from repro.kernels import ops
 from repro.kernels.bucket_probe import bucket_probe
 from repro.kernels.triangle_count import masked_gram
 from repro.kernels.simhash import simhash_pack
-from repro.kernels.hamming import hamming_cosine
+from repro.kernels.hamming import hamming_distance, hamming_sigma
 from repro.kernels.flash_attention import flash_attention
 
 RNG = np.random.default_rng(0)
@@ -72,10 +72,16 @@ def test_simhash_pack_sweep(n, k):
 def test_hamming_sweep(e, words, k):
     su = RNG.integers(0, 2**32, size=(e, words), dtype=np.uint32)
     sv = RNG.integers(0, 2**32, size=(e, words), dtype=np.uint32)
-    out = hamming_cosine(jnp.asarray(su), jnp.asarray(sv), samples=k,
-                         be=512, interpret=True)
-    want = kref.hamming_cosine_ref(jnp.asarray(su), jnp.asarray(sv), k)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
+    diff = hamming_distance(jnp.asarray(su), jnp.asarray(sv), be=512,
+                            interpret=True)
+    want = kref.hamming_distance_ref(jnp.asarray(su), jnp.asarray(sv))
+    np.testing.assert_array_equal(np.asarray(diff), np.asarray(want))
+    # the shared epilogue: σ̂ = cos(π·d/k), identical whichever lane made d
+    np.testing.assert_array_equal(
+        np.asarray(hamming_sigma(diff, k)), np.asarray(hamming_sigma(want, k)))
+    sigma = np.asarray(hamming_sigma(diff, k))
+    np.testing.assert_allclose(sigma, np.cos(np.pi * np.asarray(want) / k),
+                               atol=1e-6)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])

@@ -94,3 +94,32 @@ def test_empty_and_degenerate():
     res = query(idx, g, 10, 0.1)
     assert int(res.n_clusters) == 0
     assert np.all(np.asarray(res.labels) == -1)
+
+
+@pytest.mark.parametrize("g", [GRAPHS[0][0], GRAPHS[2][0],
+                               random_graph(40, 0.4, seed=14)])
+def test_row_reduce_and_twin_slots_match_numpy(g):
+    """The query's scatter-free pieces: per-row min/max over CSR rows and
+    the reverse slot of every half-edge, vs plain numpy."""
+    import jax.numpy as jnp
+
+    from repro.core.connectivity import row_layout, row_reduce
+    from repro.core.query import twin_slots
+
+    off = np.asarray(g.offsets)
+    eu, ev = np.asarray(g.edge_u), np.asarray(g.nbrs)
+    max_row = int(np.diff(off).max(initial=0))
+    rows = row_layout(g.offsets, g.edge_u, max_row)
+    vals = np.random.default_rng(0).integers(0, 1000, g.m2).astype(np.int32)
+    got_min = np.asarray(row_reduce(jnp.asarray(vals), rows, jnp.minimum,
+                                    jnp.int32(10 ** 6)))
+    got_max = np.asarray(row_reduce(jnp.asarray(vals), rows, jnp.maximum,
+                                    jnp.int32(-1)))
+    for v in range(g.n):
+        row = vals[off[v]:off[v + 1]]
+        assert got_min[v] == (row.min() if len(row) else 10 ** 6)
+        assert got_max[v] == (row.max() if len(row) else -1)
+    twin = np.asarray(twin_slots(g, max_row))
+    np.testing.assert_array_equal(twin, np.lexsort((eu, ev)))
+    np.testing.assert_array_equal(eu[twin], ev)
+    np.testing.assert_array_equal(ev[twin], eu)
