@@ -1,0 +1,9 @@
+"""Import paths for the benchmark's own tests (run from the repository
+root: ``python -m pytest bench/tests``)."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
