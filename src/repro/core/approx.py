@@ -32,6 +32,7 @@ import jax
 from repro.core.graph import CSRGraph
 from repro.core.index import ScanIndex, build_index
 from repro.core.lsh import approximate_similarities
+from repro.obs.trace import maybe_span
 
 #: methods and the similarity measure each one estimates
 _METHOD_MEASURE = {
@@ -180,12 +181,8 @@ class ApproxIndexBuilder:
         in the same histogram taxonomy as the rest of the serve stack.
         """
         p = self.params
-        if tracer is not None:
-            with tracer.span("index.approx_build", method=p.method,
-                             samples=p.samples, seed=p.seed, n=g.n, m=g.m):
-                index = build_index(g, self.measure,
-                                    sims=self.similarities(g))
-        else:
+        with maybe_span(tracer, "index.approx_build", method=p.method,
+                        samples=p.samples, seed=p.seed, n=g.n, m=g.m):
             index = build_index(g, self.measure, sims=self.similarities(g))
         return index, self.provenance
 

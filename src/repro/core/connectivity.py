@@ -98,9 +98,13 @@ def connected_components(
     rows: RowLayout,       # the slots' rows (the other endpoint)
     vertex_mask: jax.Array | None = None,  # bool[n] active vertices
     axis_name: str | None = None,  # set inside shard_map: slots are a shard
-) -> jax.Array:
-    """Labels int32[n]: min vertex id of the component (only meaningful where
-    vertex_mask); inactive vertices keep label = own id."""
+) -> tuple[jax.Array, jax.Array]:
+    """(labels int32[n], rounds int32): labels are the min vertex id of the
+    component (only meaningful where vertex_mask; inactive vertices keep
+    label = own id); rounds counts the propagation rounds run, the last
+    one the round that found nothing to change. Under ``vmap`` each lane
+    counts its own rounds, while the batched loop runs the most any lane
+    needs."""
     if vertex_mask is None:
         vertex_mask = jnp.ones((n,), dtype=bool)
 
@@ -108,27 +112,29 @@ def connected_components(
     big = jnp.int32(n)
 
     def body(state):
-        labels, _ = state
-        lv = jnp.where(edge_mask, labels[ev], big)
-        # min neighbor label per row (shard-local when sharded)
-        prop = row_reduce(lv, rows, jnp.minimum, big)
-        if axis_name is not None:
-            prop = jax.lax.pmin(prop, axis_name)
-        new = jnp.where(vertex_mask, jnp.minimum(labels, prop), labels)
-        # hooking: the vertex each label points at (same component) takes
-        # the proposal too, so whole trees merge in one round instead of
-        # labels crossing one edge per round (75 rounds → 7 on a 2¹⁷
-        # power-law graph). n scattered slots, not m2.
-        hook = jnp.where(vertex_mask & (prop < big), labels, big)
-        new = new.at[hook].min(prop, mode="drop")
-        # pointer jumping (path compression) — twice per round
-        new = new[new]
-        new = new[new]
-        changed = jnp.any(new != labels)
-        return new, changed
+        labels, _, rounds = state
+        with jax.named_scope("query.propagate"):
+            lv = jnp.where(edge_mask, labels[ev], big)
+            # min neighbor label per row (shard-local when sharded)
+            prop = row_reduce(lv, rows, jnp.minimum, big)
+            if axis_name is not None:
+                prop = jax.lax.pmin(prop, axis_name)
+            new = jnp.where(vertex_mask, jnp.minimum(labels, prop), labels)
+            # hooking: the vertex each label points at (same component)
+            # takes the proposal too, so whole trees merge in one round
+            # instead of labels crossing one edge per round (75 rounds → 7
+            # on a 2¹⁷ power-law graph). n scattered slots, not m2.
+            hook = jnp.where(vertex_mask & (prop < big), labels, big)
+            new = new.at[hook].min(prop, mode="drop")
+            # pointer jumping (path compression) — twice per round
+            new = new[new]
+            new = new[new]
+            changed = jnp.any(new != labels)
+        return new, changed, rounds + 1
 
     def cond(state):
         return state[1]
 
-    labels, _ = jax.lax.while_loop(cond, body, (init, jnp.bool_(True)))
-    return labels
+    labels, _, rounds = jax.lax.while_loop(
+        cond, body, (init, jnp.bool_(True), jnp.int32(0)))
+    return labels, rounds

@@ -253,17 +253,17 @@ def _sharded_query_batch(
         is_core = (mu >= 2) & (cdeg >= mu) & (theta >= eps)
 
         rows = RowLayout(epos, elast.reshape(-1), steps)
-        labels, n_clusters = cluster_labels(
+        labels, n_clusters, rounds = cluster_labels(
             n, is_core, eu, ev, esim, esim_t, eps, rows, emask=emask,
             axis_name=axis)
-        return labels, is_core, n_clusters
+        return labels, is_core, n_clusters, rounds
 
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis),) * 6 + (P(axis, None), P(axis))
         + (P(None),) * 4,
-        out_specs=(P(None), P(None), P(None)),
+        out_specs=(P(None), P(None), P(None), P(None)),
         check_vma=False,
     )
     def _shard(eu, ev, esim, esim_t, emask, epos, elast, no_s,
@@ -458,14 +458,14 @@ class ShardedQueryPlan:
 
         mus = jnp.atleast_1d(jnp.asarray(mus, jnp.int32))
         epss = jnp.atleast_1d(jnp.asarray(epss, jnp.float32))
-        labels, is_core, n_clusters = _sharded_query_batch(
+        labels, is_core, n_clusters, rounds = _sharded_query_batch(
             self.eu, self.ev, self.esim, self.esim_t, self.emask,
             self.epos, self.elast, self.no_sims,
             self.offsets_c, self.cdeg, mus, epss,
             n=self.n, max_cdeg=self.max_cdeg, mesh=self.mesh,
             axis=self.axis)
         return ClusterResult(labels=labels, is_core=is_core,
-                             n_clusters=n_clusters)
+                             n_clusters=n_clusters, rounds=rounds)
 
 
 def query_batch_sharded(

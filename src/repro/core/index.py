@@ -30,6 +30,7 @@ import numpy as np
 from repro.core.graph import CSRGraph
 from repro.core import similarity as sim_mod
 from repro.core import lsh as lsh_mod
+from repro.obs.trace import maybe_span
 
 
 @jax.tree_util.register_dataclass
@@ -91,9 +92,10 @@ def _build_orders(offsets, edge_u, nbrs, sims, n_arr):
     sims_c = jnp.concatenate([jnp.ones((n,), jnp.float32), sims])
     is_self = jnp.arange(m2c) < n
     # one global sort: row asc, σ desc; ties keep self-first, nbr asc
-    _, _, perm = jax.lax.sort(
-        (rows, _desc_key(sims_c), jnp.arange(m2c, dtype=jnp.int32)),
-        num_keys=2, is_stable=True)
+    with jax.named_scope("index.no_sort"):
+        _, _, perm = jax.lax.sort(
+            (rows, _desc_key(sims_c), jnp.arange(m2c, dtype=jnp.int32)),
+            num_keys=2, is_stable=True)
     no_nbrs = nbrs_c[perm]
     no_sims = sims_c[perm]
     no_self = is_self[perm]
@@ -108,9 +110,10 @@ def _build_orders(offsets, edge_u, nbrs, sims, n_arr):
     # key sort: μ asc, θ desc; ties keep NO's row order (v asc); inactive
     # slots pushed to the end
     mu_key = jnp.where(is_co, mu_of_slot, jnp.int32(2**30))
-    _, _, perm2 = jax.lax.sort(
-        (mu_key, _desc_key(no_sims), jnp.arange(m2c, dtype=jnp.int32)),
-        num_keys=2, is_stable=True)
+    with jax.named_scope("index.co_sort"):
+        _, _, perm2 = jax.lax.sort(
+            (mu_key, _desc_key(no_sims), jnp.arange(m2c, dtype=jnp.int32)),
+            num_keys=2, is_stable=True)
     co_vertex = rows_sorted[perm2]
     co_theta = no_sims[perm2]
     co_mu = mu_key[perm2]
@@ -133,57 +136,74 @@ def build_index(
     key: Optional[jax.Array] = None,
     degree_heuristic: bool = True,
     sims: Optional[jax.Array] = None,  # precomputed σ override (testing)
+    tracer=None,
 ) -> ScanIndex:
-    """Construct the SCAN index (exact or LSH-approximate similarities)."""
-    if sims is None:
-        if approx is None:
-            sims = sim_mod.compute_similarities(g, measure)
-        else:
-            sims = lsh_mod.approximate_similarities(
-                g,
-                measure=measure,
-                method=approx,
-                samples=samples,
-                key=key if key is not None else jax.random.PRNGKey(0),
-                degree_heuristic=degree_heuristic,
-            )
-    sims = jnp.clip(sims.astype(jnp.float32), 0.0, 1.0)
+    """Construct the SCAN index (exact or LSH-approximate similarities).
 
-    n_arr = jnp.arange(g.n, dtype=jnp.int32)
-    pad = _slot_capacity(g.m2) - g.m2
-    (offsets_c, no_nbrs, no_sims, no_self, cdeg, co_vertex, co_theta, co_mu) = (
-        _build_orders(g.offsets,
-                      jnp.pad(g.edge_u, (0, pad), constant_values=g.n),
-                      jnp.pad(g.nbrs, (0, pad), constant_values=g.n),
-                      jnp.pad(sims, (0, pad)), n_arr)
-    )
-    m2c = g.m2 + g.n
-    no_nbrs, no_sims, no_self = no_nbrs[:m2c], no_sims[:m2c], no_self[:m2c]
-    co_vertex, co_theta, co_mu = (
-        co_vertex[:g.m2], co_theta[:g.m2], co_mu[:g.m2])
-    max_cdeg = int(np.asarray(cdeg).max()) if g.n else 1
-    # segment starts per μ; CO[μ] = co_vertex[co_offsets[μ] : co_offsets[μ+1]]
-    counts = np.bincount(np.asarray(co_mu), minlength=max_cdeg + 1)
-    co_offsets = jnp.concatenate(
-        [
-            jnp.zeros((1,), jnp.int32),
-            jnp.asarray(np.cumsum(counts), dtype=jnp.int32),
-        ]
-    )
-    return ScanIndex(
-        offsets_c=offsets_c,
-        no_nbrs=no_nbrs,
-        no_sims=no_sims,
-        no_self=no_self,
-        co_offsets=co_offsets,
-        co_vertex=co_vertex,
-        co_theta=co_theta,
-        cdeg=cdeg,
-        edge_sims=sims,
-        n=g.n,
-        m2c=m2c,
-        max_cdeg=max_cdeg,
-    )
+    ``tracer`` (a :class:`repro.obs.Tracer`) wraps the build in an
+    ``index.build`` span holding ``similarity.pass`` (when σ is computed
+    here; the exact pass traces its plan, routing and groups),
+    ``index.orders`` (the global sorts, through the host read of their
+    result) and ``index.co_offsets``. Without one nothing is timed."""
+    with maybe_span(tracer, "index.build", n=g.n, m=g.m):
+        if sims is None:
+            with maybe_span(tracer, "similarity.pass",
+                            approx=approx or "exact"):
+                if approx is None:
+                    sims = sim_mod.compute_similarities(g, measure,
+                                                        tracer=tracer)
+                else:
+                    sims = lsh_mod.approximate_similarities(
+                        g,
+                        measure=measure,
+                        method=approx,
+                        samples=samples,
+                        key=(key if key is not None
+                             else jax.random.PRNGKey(0)),
+                        degree_heuristic=degree_heuristic,
+                    )
+        sims = jnp.clip(sims.astype(jnp.float32), 0.0, 1.0)
+
+        with maybe_span(tracer, "index.orders"):
+            n_arr = jnp.arange(g.n, dtype=jnp.int32)
+            pad = _slot_capacity(g.m2) - g.m2
+            (offsets_c, no_nbrs, no_sims, no_self, cdeg, co_vertex, co_theta,
+             co_mu) = _build_orders(
+                g.offsets,
+                jnp.pad(g.edge_u, (0, pad), constant_values=g.n),
+                jnp.pad(g.nbrs, (0, pad), constant_values=g.n),
+                jnp.pad(sims, (0, pad)), n_arr)
+            m2c = g.m2 + g.n
+            no_nbrs, no_sims, no_self = (no_nbrs[:m2c], no_sims[:m2c],
+                                         no_self[:m2c])
+            co_vertex, co_theta, co_mu = (
+                co_vertex[:g.m2], co_theta[:g.m2], co_mu[:g.m2])
+            # the host read waits for the sorts, so the span covers them
+            max_cdeg = int(np.asarray(cdeg).max()) if g.n else 1
+        with maybe_span(tracer, "index.co_offsets"):
+            # segment starts per μ;
+            # CO[μ] = co_vertex[co_offsets[μ] : co_offsets[μ+1]]
+            counts = np.bincount(np.asarray(co_mu), minlength=max_cdeg + 1)
+            co_offsets = jnp.concatenate(
+                [
+                    jnp.zeros((1,), jnp.int32),
+                    jnp.asarray(np.cumsum(counts), dtype=jnp.int32),
+                ]
+            )
+        return ScanIndex(
+            offsets_c=offsets_c,
+            no_nbrs=no_nbrs,
+            no_sims=no_sims,
+            no_self=no_self,
+            co_offsets=co_offsets,
+            co_vertex=co_vertex,
+            co_theta=co_theta,
+            cdeg=cdeg,
+            edge_sims=sims,
+            n=g.n,
+            m2c=m2c,
+            max_cdeg=max_cdeg,
+        )
 
 
 def get_cores(index: ScanIndex, mu: int, eps: float) -> jax.Array:

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,9 @@ class ClusterResult:
     labels: jax.Array      # int32[n]; component id (min core vertex id) or -1
     is_core: jax.Array     # bool[n]
     n_clusters: jax.Array  # int32 scalar
+    # propagation rounds the connectivity loop ran for this answer (int32
+    # scalar; per lane under query_batch); None where not computed here
+    rounds: Optional[jax.Array] = None
 
 
 def twin_slots(g: CSRGraph, max_row: int) -> jax.Array:
@@ -60,7 +64,8 @@ def twin_slots(g: CSRGraph, max_row: int) -> jax.Array:
 
 def cluster_labels(n: int, is_core, eu, ev, esim, esim_t, eps,
                    rows: RowLayout, emask=None, axis_name=None):
-    """Steps 2–4 of a query from the core mask → (labels, n_clusters).
+    """Steps 2–4 of a query from the core mask → (labels, n_clusters,
+    rounds), ``rounds`` the connectivity loop's propagation rounds.
 
     Every per-vertex reduction runs over the vertex's own CSR row. For
     the border step slot (v, u) stands for half-edge (u, v), whose σ is
@@ -76,30 +81,33 @@ def cluster_labels(n: int, is_core, eu, ev, esim, esim_t, eps,
         sim_ok = sim_ok & emask
         sim_t_ok = sim_t_ok & emask
     # ε-similar core–core half-edges → connectivity
-    labels0 = connected_components(n, ev, sim_ok & core_u & core_v, rows,
-                                   vertex_mask=is_core, axis_name=axis_name)
+    labels0, rounds = connected_components(
+        n, ev, sim_ok & core_u & core_v, rows, vertex_mask=is_core,
+        axis_name=axis_name)
     labels = jnp.where(is_core, labels0, jnp.int32(-1))
 
     # ---- border assignment (Algorithm 4, deterministic variant) ----
-    border = sim_t_ok & core_v & ~core_u      # row v non-core, nbr u core
-    neg = jnp.float32(-1.0)
-    best_sim = row_reduce(jnp.where(border, esim_t, neg), rows,
-                          jnp.maximum, neg)
-    if axis_name is not None:
-        best_sim = jax.lax.pmax(best_sim, axis_name)
-    best_row = best_sim[eu]
-    tie = border & (esim_t >= best_row) & (best_row > neg)
-    big = jnp.int32(n)
-    best_core = row_reduce(jnp.where(tie, ev, big), rows, jnp.minimum, big)
-    if axis_name is not None:
-        best_core = jax.lax.pmin(best_core, axis_name)
-    has_border = best_core < big
-    border_label = labels0[jnp.clip(best_core, 0, n - 1)]
-    labels = jnp.where(~is_core & has_border, border_label, labels)
+    with jax.named_scope("query.borders"):
+        border = sim_t_ok & core_v & ~core_u  # row v non-core, nbr u core
+        neg = jnp.float32(-1.0)
+        best_sim = row_reduce(jnp.where(border, esim_t, neg), rows,
+                              jnp.maximum, neg)
+        if axis_name is not None:
+            best_sim = jax.lax.pmax(best_sim, axis_name)
+        best_row = best_sim[eu]
+        tie = border & (esim_t >= best_row) & (best_row > neg)
+        big = jnp.int32(n)
+        best_core = row_reduce(jnp.where(tie, ev, big), rows, jnp.minimum,
+                               big)
+        if axis_name is not None:
+            best_core = jax.lax.pmin(best_core, axis_name)
+        has_border = best_core < big
+        border_label = labels0[jnp.clip(best_core, 0, n - 1)]
+        labels = jnp.where(~is_core & has_border, border_label, labels)
 
     # count distinct clusters = number of cores that are their own label
     n_clusters = jnp.sum(is_core & (labels == jnp.arange(n)))
-    return labels, n_clusters
+    return labels, n_clusters, rounds
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -112,10 +120,13 @@ def query(index: ScanIndex, g: CSRGraph, mu, eps) -> ClusterResult:
     rows = row_layout(g.offsets, g.edge_u, max_row)
     esim_t = index.edge_sims[twin_slots(g, max_row)]
 
-    is_core = get_cores(index, mu, eps)
-    labels, n_clusters = cluster_labels(index.n, is_core, g.edge_u, g.nbrs,
-                                        index.edge_sims, esim_t, eps, rows)
-    return ClusterResult(labels=labels, is_core=is_core, n_clusters=n_clusters)
+    with jax.named_scope("query.cores"):
+        is_core = get_cores(index, mu, eps)
+    labels, n_clusters, rounds = cluster_labels(
+        index.n, is_core, g.edge_u, g.nbrs, index.edge_sims, esim_t, eps,
+        rows)
+    return ClusterResult(labels=labels, is_core=is_core,
+                         n_clusters=n_clusters, rounds=rounds)
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -130,7 +141,8 @@ def query_batch(index: ScanIndex, g: CSRGraph, mus, epss) -> ClusterResult:
     index arrays are closed over (broadcast), only the parameters vary.
     The inner connectivity ``while_loop`` runs until every batch member has
     converged; min-label propagation is monotone so already-converged
-    members are fixed points and extra rounds are no-ops.
+    members are fixed points and extra rounds are no-ops. ``rounds``
+    int32[B] holds each member's own round count; the call ran their max.
     """
     mus = jnp.atleast_1d(jnp.asarray(mus, jnp.int32))
     epss = jnp.atleast_1d(jnp.asarray(epss, jnp.float32))

@@ -83,6 +83,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 import weakref
 from typing import Dict, List, Optional, Tuple
 
@@ -101,6 +102,7 @@ from repro.backend.policy import (
     LANE_COMPILED, LANE_INTERPRET, LANE_REF, ExecutionPolicy, default_policy,
 )
 from repro.core.graph import CSRGraph, to_dense
+from repro.obs.trace import maybe_span
 
 MEASURES = ("cosine", "jaccard")
 
@@ -424,6 +426,7 @@ class SimilarityPlan:
         measure: str = "cosine",
         chunk: int = 1 << 16,
         policy: Optional[ExecutionPolicy] = None,
+        tracer=None,
     ) -> jax.Array:
         """σ (or triangle counts with measure='_count') for an edge subset.
 
@@ -432,6 +435,13 @@ class SimilarityPlan:
         Pallas lanes run the sorted-probe kernel on identical gathered
         operands (bit-identical on unweighted σ, ULP on weighted). Lane
         decisions count under ``backend.lane.bucket_probe.<lane>``.
+
+        With a ``tracer`` (:class:`repro.obs.Tracer`) the host routing is
+        a ``similarity.route`` span and each group a ``similarity.group``
+        span (``pe``, ``te``, ``lane``, ``edges``, ``chunks``); chunks get
+        no span of their own, only their blocking host copies are timed,
+        summed into one ``similarity.device_wait`` event per group, and
+        counted under ``similarity.chunks``. Without one nothing is timed.
         """
         if measure not in MEASURES + ("_count",):
             raise ValueError(f"measure must be one of {MEASURES}")
@@ -444,10 +454,11 @@ class SimilarityPlan:
         if total == 0:
             return jnp.zeros((0,), out_dt)
 
-        pu, pv, keys = self.route(eu, ev)
-        order = np.argsort(keys, kind="stable")
-        bounds = np.flatnonzero(np.diff(keys[order])) + 1
-        groups = np.split(order, bounds)
+        with maybe_span(tracer, "similarity.route", edges=total):
+            pu, pv, keys = self.route(eu, ev)
+            order = np.argsort(keys, kind="stable")
+            bounds = np.flatnonzero(np.diff(keys[order])) + 1
+            groups = np.split(order, bounds)
 
         out = np.empty(total, out_dt)
         shapes = []
@@ -473,39 +484,53 @@ class SimilarityPlan:
                 # chunk so compile cost stays proportional to the profile
                 csize = min(csize, _pow2ceil(
                     pol.profile.probe_interpret_chunk))
+            chunks = -(-len(idx) // csize)
             sentinel_p = self.nbr_blocks[cp].shape[0] - 1
-            for s in range(0, len(idx), csize):
-                sub = idx[s: s + csize]
-                pad = csize - len(sub)
-                args = dict(
-                    p0=_pad1(self.vrow[pu[sub]], pad, sentinel_p),
-                    pt=_pad1(self.vtiles[pu[sub]], pad, 0),
-                    t0=_pad1(self.vrow[pv[sub]], pad,
-                             self.nbr_blocks[ct].shape[0] - 1),
-                    tt=_pad1(self.vtiles[pv[sub]], pad, 0),
-                    ceu=_pad1(eu[sub].astype(np.int32), pad, 0),
-                    cev=_pad1(ev[sub].astype(np.int32), pad, 0),
-                    cew=_pad1(ew[sub], pad, 0.0),
-                )
-                operands = (
-                    jnp.asarray(args["p0"]), jnp.asarray(args["pt"]),
-                    jnp.asarray(args["t0"]), jnp.asarray(args["tt"]),
-                    jnp.asarray(args["ceu"]), jnp.asarray(args["cev"]),
-                    jnp.asarray(args["cew"]),
-                    self.nbr_blocks[cp], self.wgt_blocks[cp],
-                    self.nbr_blocks[ct], self.wgt_blocks[ct],
-                    self.norms, self.cdeg,
-                )
-                if lane == LANE_REF:
-                    res = _bucket_sims_chunk(
-                        *operands, sp=sp, st=st, measure=measure)
-                else:
-                    res = _bucket_sims_chunk_pallas(
-                        *operands, sp=sp, st=st, measure=measure,
-                        be=min(pol.profile.probe_be, csize),
-                        bt=pol.profile.probe_bt,
-                        interpret=pol.interpret(lane))
-                out[sub] = np.asarray(res)[: len(sub)]
+            wait = 0.0
+            with maybe_span(tracer, "similarity.group", pe=pe, te=te,
+                            lane=lane, edges=len(idx), chunks=chunks):
+                for s in range(0, len(idx), csize):
+                    sub = idx[s: s + csize]
+                    pad = csize - len(sub)
+                    args = dict(
+                        p0=_pad1(self.vrow[pu[sub]], pad, sentinel_p),
+                        pt=_pad1(self.vtiles[pu[sub]], pad, 0),
+                        t0=_pad1(self.vrow[pv[sub]], pad,
+                                 self.nbr_blocks[ct].shape[0] - 1),
+                        tt=_pad1(self.vtiles[pv[sub]], pad, 0),
+                        ceu=_pad1(eu[sub].astype(np.int32), pad, 0),
+                        cev=_pad1(ev[sub].astype(np.int32), pad, 0),
+                        cew=_pad1(ew[sub], pad, 0.0),
+                    )
+                    operands = (
+                        jnp.asarray(args["p0"]), jnp.asarray(args["pt"]),
+                        jnp.asarray(args["t0"]), jnp.asarray(args["tt"]),
+                        jnp.asarray(args["ceu"]), jnp.asarray(args["cev"]),
+                        jnp.asarray(args["cew"]),
+                        self.nbr_blocks[cp], self.wgt_blocks[cp],
+                        self.nbr_blocks[ct], self.wgt_blocks[ct],
+                        self.norms, self.cdeg,
+                    )
+                    if lane == LANE_REF:
+                        res = _bucket_sims_chunk(
+                            *operands, sp=sp, st=st, measure=measure)
+                    else:
+                        res = _bucket_sims_chunk_pallas(
+                            *operands, sp=sp, st=st, measure=measure,
+                            be=min(pol.profile.probe_be, csize),
+                            bt=pol.profile.probe_bt,
+                            interpret=pol.interpret(lane))
+                    if tracer is None:
+                        out[sub] = np.asarray(res)[: len(sub)]
+                        continue
+                    # the host copy blocks on the chunk's device work
+                    tw = time.perf_counter()
+                    host = np.asarray(res)
+                    wait += time.perf_counter() - tw
+                    out[sub] = host[: len(sub)]
+            if tracer is not None:
+                tracer.event("similarity.device_wait", wait)
+                tracer.inc("similarity.chunks", chunks)
         object.__setattr__(self, "last_group_shapes", tuple(shapes))
         return jnp.asarray(out)
 
@@ -688,21 +713,24 @@ def _cache_plan(g: CSRGraph, key, plan: SimilarityPlan) -> None:
 
 
 def plan_for(g: CSRGraph,
-             hub_tile: Optional[int] = None) -> SimilarityPlan:
+             hub_tile: Optional[int] = None, *,
+             tracer=None) -> SimilarityPlan:
     """The bucketed :class:`SimilarityPlan` for ``g``, cached per live graph
     object so construction, the LSH exact pass, triangle counting, and the
     incremental-update path share one set of device blocks. Entries are
     evicted by a ``weakref.finalize`` on the graph, so a plan never
     outlives its graph. ``hub_tile`` defaults to the active execution
     policy's autotune profile (legacy constant ``HUB_TILE`` when
-    untuned)."""
+    untuned). With a ``tracer``, a cache miss's build is a
+    ``similarity.plan`` span."""
     if hub_tile is None:
         hub_tile = default_policy().profile.hub_tile
     key = (id(g), hub_tile)
     ent = _PLAN_CACHE.get(key)
     if ent is not None and ent[0]() is g:
         return ent[1]
-    plan = SimilarityPlan.build(g, hub_tile)
+    with maybe_span(tracer, "similarity.plan", n=g.n, m2=g.m2):
+        plan = SimilarityPlan.build(g, hub_tile)
     _cache_plan(g, key, plan)
     return plan
 
@@ -742,6 +770,8 @@ def edge_similarities_subset(
     ew: jax.Array,
     measure: str = "cosine",
     chunk: int = 1 << 16,
+    *,
+    tracer=None,
 ) -> jax.Array:
     """Exact σ for an arbitrary subset of edges (endpoint arrays).
 
@@ -749,19 +779,23 @@ def edge_similarities_subset(
     exact pass under LSH, and the incremental-update frontier recompute.
     Edges route to per-degree-class kernels with power-of-two chunk
     shapes, so repeated calls (e.g. update batches at the same pow2 size)
-    reuse one compiled function per class pair.
+    reuse one compiled function per class pair. ``tracer`` traces the
+    plan build and the pass (:meth:`SimilarityPlan.edge_sims`).
     """
     if measure not in MEASURES:
         raise ValueError(f"measure must be one of {MEASURES}")
-    return plan_for(g).edge_sims(eu, ev, ew, measure, chunk)
+    return plan_for(g, tracer=tracer).edge_sims(eu, ev, ew, measure, chunk,
+                                                tracer=tracer)
 
 
 def compute_similarities(
-    g: CSRGraph, measure: str = "cosine", chunk: int = 1 << 16
+    g: CSRGraph, measure: str = "cosine", chunk: int = 1 << 16, *,
+    tracer=None,
 ) -> jax.Array:
     """Exact σ for every half-edge, float32[m2]. Host-orchestrated routing
     over the degree-bucketed engine."""
-    return edge_similarities_subset(g, g.edge_u, g.nbrs, g.wgts, measure, chunk)
+    return edge_similarities_subset(g, g.edge_u, g.nbrs, g.wgts, measure,
+                                    chunk, tracer=tracer)
 
 
 def triangle_counts(g: CSRGraph) -> jax.Array:

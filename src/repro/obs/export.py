@@ -1,54 +1,14 @@
 """Export surfaces for a :class:`~repro.obs.metrics.MetricsRegistry`:
-JSON files, Prometheus text exposition, and a periodic one-line dump for
-long-running ``scan_serve serve``/``update`` processes.
+JSON files and a periodic one-line dump for long-running
+``scan_serve serve``/``update`` processes.
 """
 from __future__ import annotations
 
 import asyncio
 import json
-import re
 from typing import Iterable, Optional
 
-__all__ = ["to_prometheus", "write_json", "render_line", "dump_loop"]
-
-_NAME_RE = re.compile(r"[^a-zA-Z0-9_:]")
-
-
-def _prom_name(name: str) -> str:
-    """Metric name → Prometheus-legal name (dots and dashes become _)."""
-    out = _NAME_RE.sub("_", name)
-    if out and out[0].isdigit():
-        out = "_" + out
-    return out
-
-
-def to_prometheus(snapshot: dict, prefix: str = "repro_") -> str:
-    """Render a registry snapshot in the Prometheus text exposition
-    format. Histograms follow the standard convention: cumulative
-    ``_bucket{le="..."}`` series (underflow folds into the first finite
-    edge, overflow into ``+Inf``), plus ``_sum`` and ``_count``.
-    """
-    lines = []
-    for name, value in snapshot.get("counters", {}).items():
-        p = prefix + _prom_name(name)
-        lines.append(f"# TYPE {p} counter")
-        lines.append(f"{p} {value}")
-    for name, value in snapshot.get("gauges", {}).items():
-        p = prefix + _prom_name(name)
-        lines.append(f"# TYPE {p} gauge")
-        lines.append(f"{p} {value}")
-    for name, h in snapshot.get("histograms", {}).items():
-        p = prefix + _prom_name(name)
-        lines.append(f"# TYPE {p} histogram")
-        acc = 0
-        for edge, count in zip(h["edges"], h["counts"]):
-            acc += count
-            lines.append(f'{p}_bucket{{le="{edge:g}"}} {acc}')
-        acc += h["counts"][len(h["edges"])]
-        lines.append(f'{p}_bucket{{le="+Inf"}} {acc}')
-        lines.append(f"{p}_sum {h['sum']}")
-        lines.append(f"{p}_count {h['count']}")
-    return "\n".join(lines) + "\n"
+__all__ = ["write_json", "render_line", "dump_loop"]
 
 
 def write_json(snapshot: dict, path: str) -> None:

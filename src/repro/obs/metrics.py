@@ -78,7 +78,7 @@ class Gauge:
 
     MODES = ("sum", "max")
 
-    __slots__ = ("_lock", "_value", "mode")
+    __slots__ = ("_lock", "_value", "_unset", "mode")
 
     def __init__(self, mode: str = "sum") -> None:
         if mode not in self.MODES:
@@ -86,23 +86,31 @@ class Gauge:
                              f"got {mode!r}")
         self._lock = threading.Lock()
         self._value = 0.0
+        self._unset = True      # no reading yet: the 0.0 is no watermark
         self.mode = mode
 
     def set(self, value: float) -> None:
         with self._lock:
             self._value = float(value)
+            self._unset = False
 
     def add(self, amount: float) -> None:
         with self._lock:
             self._value += amount
+            self._unset = False
 
     def merge_value(self, value: float) -> None:
-        """Fold one peer registry's reading in, per this gauge's mode."""
+        """Fold one peer registry's reading in, per this gauge's mode.
+        The first reading into an unset max-mode gauge is taken as is
+        (an all-negative fleet keeps its negative watermark)."""
         with self._lock:
-            if self.mode == "max":
+            if self.mode == "max" and not self._unset:
                 self._value = max(self._value, float(value))
+            elif self.mode == "max":
+                self._value = float(value)
             else:
                 self._value += float(value)
+            self._unset = False
 
     @property
     def value(self) -> float:

@@ -8,6 +8,14 @@ histogram of the same name, so *every span taxonomy is automatically a
 latency histogram taxonomy*: ``live.apply_delta`` the span and
 ``live.apply_delta`` the histogram are the same measurements.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+for its lifetime, so a profiler trace carries the program's spans on its
+host plane, on the clock its device events use (its attributes ride
+along as event stats while a trace is recording). With no trace
+recording the annotation is an inactive ``TraceMe``, well under a
+microsecond. ``event()`` records durations measured after the fact and
+reaches the ring and the histograms only.
+
 Nesting is tracked with a :mod:`contextvars` variable, so concurrent
 asyncio tasks each see their own span stack (a span opened in task A is
 never the parent of a span opened in task B). Plain
@@ -27,7 +35,9 @@ import time
 from collections import deque
 from typing import Iterator, List, Optional
 
-__all__ = ["Span", "Tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "maybe_span"]
 
 # the innermost open span of the current task/thread (contextvar: each
 # asyncio task and each thread sees its own chain)
@@ -76,8 +86,14 @@ class Tracer:
                   parent_id=parent.span_id if parent is not None else None,
                   t_start=time.monotonic(), attrs=dict(attrs))
         token = _CURRENT.set(sp)
+        # attributes reach the profiler only while it records: encoding
+        # them is the annotation's one cost that is not constant
+        ann = (TraceAnnotation(name, **attrs)
+               if attrs and TraceAnnotation.is_enabled()
+               else TraceAnnotation(name))
         try:
-            yield sp
+            with ann:
+                yield sp
         finally:
             _CURRENT.reset(token)
             self._finish(sp, time.monotonic() - sp.t_start)
@@ -94,6 +110,13 @@ class Tracer:
                            if t_start is None else t_start),
                   attrs=dict(attrs))
         self._finish(sp, duration_s)
+
+    def inc(self, name: str, amount: int = 1) -> None:
+        """Add to the registry counter ``name`` (no-op without a
+        registry): lets a traced code path count work through the one
+        tracer it is handed."""
+        if self.registry is not None:
+            self.registry.inc(name, amount)
 
     def _finish(self, sp: Span, duration_s: float) -> None:
         self._events.append({
@@ -115,5 +138,10 @@ class Tracer:
             evs = [e for e in evs if e["name"] == name]
         return evs
 
-    def clear(self) -> None:
-        self._events.clear()
+
+def maybe_span(tracer: Optional[Tracer], name: str, **attrs):
+    """``tracer.span(name, **attrs)``, or a context that records nothing
+    when ``tracer`` is None (core paths are traced only on request)."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name, **attrs)

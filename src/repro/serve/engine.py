@@ -75,8 +75,13 @@ Telemetry (``repro.obs``): every engine owns a ``MetricsRegistry`` + a
 ``Tracer``. Per request the engine records an ``engine.cache_lookup``
 span, an ``engine.queue_wait`` event (enqueue → flush pickup), and an
 ``engine.e2e`` histogram sample (request → resolved, cache hits
-included); per flush an ``engine.batch_assembly`` event and one
-``engine.device_call`` span per bucket; plus counters for every legacy
+included); per flush an ``engine.batch_assembly`` event, an
+``engine.flush`` span and, inside it, one ``engine.device_call`` span per
+bucket, whose child ``engine.device_wait`` waits for the device before
+the host copies (so a flush's host time is ``engine.flush`` minus
+``engine.device_wait``); the counters ``query.rounds`` (propagation
+rounds each call ran: its slowest lane's) and ``query.lane_rounds``
+(summed over the call's slots); plus counters for every legacy
 ``stats`` key, an ``engine.queue_depth`` / ``engine.offload_depth``
 gauge pair, and an ``engine.jit_recompiles`` counter fed by jit
 cache-size deltas measured around each device call — a steady-state
@@ -97,6 +102,7 @@ from collections.abc import Mapping
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.backend.policy import ExecutionPolicy
@@ -569,11 +575,16 @@ class MicroBatchEngine:
             deadline = asyncio.get_running_loop().time() + self.cfg.flush_ms / 1e3
             while len(batch) < self.cfg.max_batch:
                 timeout = deadline - asyncio.get_running_loop().time()
-                if timeout <= 0:
-                    break
                 try:
-                    item = await asyncio.wait_for(self._queue.get(), timeout)
-                except asyncio.TimeoutError:
+                    if timeout <= 0:
+                        # past the deadline, still take what already
+                        # waits: a pause of the loop (a GC pass) must not
+                        # leave queued requests to the next device call
+                        item = self._queue.get_nowait()
+                    else:
+                        item = await asyncio.wait_for(self._queue.get(),
+                                                      timeout)
+                except (asyncio.TimeoutError, asyncio.QueueEmpty):
                     break
                 if item is None:
                     self._note_assembly(t_asm, batch)
@@ -624,38 +635,43 @@ class MicroBatchEngine:
         artifacts differ). A failing bucket rejects only its own
         waiters — sibling buckets and the collector keep running (later
         requests must not hang on a dead loop)."""
-        now = time.monotonic()
-        buckets: dict[tuple, list] = {}
-        for item in batch:
-            if item[0] is _DRAIN:
-                # barrier marker: everything queued before it is in this
-                # or an earlier (already flushed) batch; real items in
-                # *this* batch flush below, before any awaiter of the
-                # barrier future runs (the loop is single-threaded).
-                # A cancelled waiter (wait_for timeout) must not kill the
-                # collector with InvalidStateError.
-                if not item[3].done():
-                    item[3].set_result(None)
-                continue
-            # queue wait = enqueue → flush pickup, per request (the batch
-            # deadline shows up here; tail growth means admission trouble)
-            wait_name = ("engine.seed_queue_wait"
-                         if item[1] == _KIND_SEED else "engine.queue_wait")
-            self.tracer.event(wait_name, now - item[4],
-                              t_start=item[4], fingerprint=item[0][:12])
-            buckets.setdefault((item[0], item[1]), []).append(item)
-        self.registry.gauge("engine.queue_depth").set(self._queue.qsize())
-        for (fp, kind), bucket in buckets.items():
-            try:
-                if kind == _KIND_SEED:
-                    self._execute_seeds(fp, bucket)
-                else:
-                    self._execute(fp, bucket)
-            except Exception as e:  # noqa: BLE001
-                self.registry.inc("engine.bucket_failures")
-                for item in bucket:
+        with self.tracer.span("engine.flush", batch=len(batch)):
+            now = time.monotonic()
+            buckets: dict[tuple, list] = {}
+            for item in batch:
+                if item[0] is _DRAIN:
+                    # barrier marker: everything queued before it is in
+                    # this or an earlier (already flushed) batch; real
+                    # items in *this* batch flush below, before any
+                    # awaiter of the barrier future runs (the loop is
+                    # single-threaded). A cancelled waiter (wait_for
+                    # timeout) must not kill the collector with
+                    # InvalidStateError.
                     if not item[3].done():
-                        item[3].set_exception(e)
+                        item[3].set_result(None)
+                    continue
+                # queue wait = enqueue → flush pickup, per request (the
+                # batch deadline shows up here; tail growth means
+                # admission trouble)
+                wait_name = ("engine.seed_queue_wait"
+                             if item[1] == _KIND_SEED
+                             else "engine.queue_wait")
+                self.tracer.event(wait_name, now - item[4],
+                                  t_start=item[4], fingerprint=item[0][:12])
+                buckets.setdefault((item[0], item[1]), []).append(item)
+            self.registry.gauge("engine.queue_depth").set(
+                self._queue.qsize())
+            for (fp, kind), bucket in buckets.items():
+                try:
+                    if kind == _KIND_SEED:
+                        self._execute_seeds(fp, bucket)
+                    else:
+                        self._execute(fp, bucket)
+                except Exception as e:  # noqa: BLE001
+                    self.registry.inc("engine.bucket_failures")
+                    for item in bucket:
+                        if not item[3].done():
+                            item[3].set_exception(e)
 
     # ------------------------------------------------------------------
     # per-bucket execution
@@ -715,8 +731,10 @@ class MicroBatchEngine:
                     need=len(need), warmed=len(warm), slots=len(slots),
                     shards=self.cfg.shards or 1, lane=lane):
                 res = self._device_call(fp, index, g, mus, epss)
-                # host conversion blocks on the device, so the span (and
-                # the same-named histogram) covers real compute+transfer
+                # the span (and the same-named histogram) covers the
+                # device's work, then the host copies of its answer
+                with self.tracer.span("engine.device_wait"):
+                    jax.block_until_ready(res)
                 labels = np.asarray(res.labels)
                 is_core = np.asarray(res.is_core)
                 n_clusters = np.asarray(res.n_clusters)
@@ -724,6 +742,11 @@ class MicroBatchEngine:
             if jit_delta > 0:
                 self.registry.inc("engine.jit_recompiles", jit_delta)
             self.registry.inc("engine.device_queries")
+            if res.rounds is not None:
+                # the batched loop runs until its slowest slot converges
+                rounds = np.asarray(res.rounds)
+                self.registry.inc("query.rounds", int(rounds.max()))
+                self.registry.inc("query.lane_rounds", int(rounds.sum()))
             self.registry.inc("engine.warmed", len(warm))
             for i, key in enumerate(need + warm):
                 # copy: row views would pin the whole padded batch array

@@ -206,21 +206,29 @@ class LiveIndexService:
                provenance: Optional[IndexProvenance] = None) -> str:
         """Build (or adopt) an index for ``name``, persist snapshot v0,
         register it with the engine; → fingerprint. ``provenance`` tags an
-        adopted index (default: exact)."""
+        adopted index (default: exact).
+
+        Traced as ``live.create`` ⊃ ``index.build`` (when built here),
+        ``live.fingerprint``, ``store.save`` and ``engine.register``."""
         if name in self._live:
             raise ValueError(f"index {name!r} already live")
-        if index is None:
-            index = build_index(g, self.measure)
-        if provenance is None:
-            provenance = EXACT_PROVENANCE
-        fp = index_fingerprint(index, g)
-        profile = self.engine.policy.profile
-        self.catalog.store(name).save(index, g, version=0,
-                                      measure=self.measure,
-                                      provenance=provenance,
-                                      profile=profile)
-        self.engine.register(index, g, fingerprint=fp,
-                             provenance=provenance)
+        tracer = self.engine.tracer
+        with tracer.span("live.create", index=name, n=g.n, m=g.m):
+            if index is None:
+                index = build_index(g, self.measure, tracer=tracer)
+            if provenance is None:
+                provenance = EXACT_PROVENANCE
+            with tracer.span("live.fingerprint", index=name):
+                fp = index_fingerprint(index, g)
+            profile = self.engine.policy.profile
+            with tracer.span("store.save", index=name):
+                self.catalog.store(name).save(index, g, version=0,
+                                              measure=self.measure,
+                                              provenance=provenance,
+                                              profile=profile)
+            with tracer.span("engine.register", index=name):
+                self.engine.register(index, g, fingerprint=fp,
+                                     provenance=provenance)
         self._live[name] = _Live(index=index, g=g, fp=fp, seq=0,
                                  snapshot_seq=0, provenance=provenance,
                                  profile=profile)

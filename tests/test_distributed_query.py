@@ -41,7 +41,7 @@ _PROG = textwrap.dedent("""
         idx = build_index(g, "cosine")
         ref = query_batch(idx, g, MUS, EPSS)
         out = query_batch_sharded(idx, g, MUS, EPSS, mesh=mesh)
-        for field in ("labels", "is_core", "n_clusters"):
+        for field in ("labels", "is_core", "n_clusters", "rounds"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(out, field)),
                 np.asarray(getattr(ref, field)),
@@ -114,6 +114,8 @@ def test_sharded_query_single_device_degenerate():
                                   np.asarray(ref.is_core))
     np.testing.assert_array_equal(np.asarray(out.n_clusters),
                                   np.asarray(ref.n_clusters))
+    np.testing.assert_array_equal(np.asarray(out.rounds),
+                                  np.asarray(ref.rounds))
 
     plan = ShardedQueryPlan(idx, g, query_mesh(1))
     for _ in range(2):                       # same plan, repeated calls

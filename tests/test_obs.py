@@ -16,7 +16,7 @@ import pytest
 
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, Tracer,
                        dump_loop, hist_delta, hist_quantile, render_line,
-                       to_prometheus, write_json)
+                       write_json)
 
 
 # ---------------------------------------------------------------- metrics
@@ -285,20 +285,6 @@ def _sample_registry():
     for v in (0.001, 0.01, 0.1):
         reg.observe("engine.e2e", v)
     return reg
-
-
-def test_to_prometheus_cumulative_buckets():
-    text = to_prometheus(_sample_registry().snapshot())
-    assert "# TYPE repro_engine_requests counter" in text
-    assert "repro_engine_requests 3" in text
-    assert "repro_engine_queue_depth 2" in text
-    assert '# TYPE repro_engine_e2e histogram' in text
-    assert 'repro_engine_e2e_bucket{le="+Inf"} 3' in text
-    assert "repro_engine_e2e_count 3" in text
-    # bucket series must be cumulative (monotone nondecreasing)
-    counts = [int(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
-              if ln.startswith("repro_engine_e2e_bucket")]
-    assert counts == sorted(counts)
 
 
 def test_write_json_round_trips(tmp_path):

@@ -212,6 +212,25 @@ def test_engine_executes_quantized_eps_not_raw():
     np.testing.assert_array_equal(b.labels, np.asarray(ref.labels))
 
 
+def test_engine_batches_what_waits_past_its_deadline():
+    """Requests already queued when the flush deadline passes ride the
+    same device call: a pause of the event loop (a GC pass) between the
+    first pickup and the drain must not push them to the next call. With
+    ``flush_ms=0`` the deadline has passed at the first pickup."""
+    g, idx, _ = _graph_and_index(n=60, deg=5.0, seed=4)
+    engine = MicroBatchEngine(idx, g, config=EngineConfig(
+        max_batch=8, flush_ms=0.0, warm_ahead=False))
+
+    async def main():
+        async with engine:
+            return await asyncio.gather(*[engine.query(2, eps) for eps in
+                                          (0.2, 0.3, 0.4, 0.5, 0.6)])
+
+    asyncio.run(main())
+    assert engine.stats["device_queries"] == 1
+    assert engine.stats["requests"] == 5
+
+
 def test_cache_fingerprint_invalidation():
     c = ResultCache(capacity=8)
     c.put("fp1", 2, 0.5, "a")
