@@ -81,6 +81,7 @@ Supported measures (paper §2.1/§4.1.1):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
@@ -112,6 +113,11 @@ BUCKET_FLOOR = 8
 HUB_TILE = 2048
 # per-chunk element budget for the transient gathered row matrices
 CHUNK_ELEMS = 1 << 22
+# chunk programs the similarity pass keeps queued on the device: before
+# dispatching chunk i it waits for chunk i - INFLIGHT_CHUNKS. A deeper
+# window is no faster on a v5e: the runtime's own queue then blocks the
+# dispatches instead, and the wait no longer shows as a wait
+INFLIGHT_CHUNKS = 8
 # fewest edges per compiled bucket_probe chunk: one full 128-lane row
 KERNEL_LANE_EDGES = 128
 
@@ -417,6 +423,26 @@ class SimilarityPlan:
                                 np.asarray(ev, np.int64))
         return len(np.unique(keys))
 
+    def _stage(self, idx, pu, pv, eu, ev, ew, cp: int, ct: int,
+               rows: int) -> np.ndarray:
+        """A group's chunk operands, int32[7, rows]: probe first tile row
+        and tile count, target first tile row and tile count, the edge's
+        endpoints and its weight (bit-cast). Slots past the group's edges
+        probe the sentinel rows with weight 0."""
+        staged = np.empty((7, rows), np.int32)
+        k = len(idx)
+        staged[0, :k] = self.vrow[pu[idx]]
+        staged[1, :k] = self.vtiles[pu[idx]]
+        staged[2, :k] = self.vrow[pv[idx]]
+        staged[3, :k] = self.vtiles[pv[idx]]
+        staged[4, :k] = eu[idx]
+        staged[5, :k] = ev[idx]
+        staged[6, :k] = ew[idx].view(np.int32)
+        staged[:, k:] = np.array(
+            [self.nbr_blocks[cp].shape[0] - 1, 0,
+             self.nbr_blocks[ct].shape[0] - 1, 0, 0, 0, 0], np.int32)[:, None]
+        return staged
+
     # -- the engine ---------------------------------------------------------
     def edge_sims(
         self,
@@ -436,12 +462,19 @@ class SimilarityPlan:
         operands (bit-identical on unweighted σ, ULP on weighted). Lane
         decisions count under ``backend.lane.bucket_probe.<lane>``.
 
+        Each group's operands go to the device in one copy; its chunks
+        slice them there and queue without a host round trip, at most
+        ``INFLIGHT_CHUNKS`` in flight. σ stays on the device: the result
+        is gathered into input order from the chunks' slots.
+
         With a ``tracer`` (:class:`repro.obs.Tracer`) the host routing is
         a ``similarity.route`` span and each group a ``similarity.group``
         span (``pe``, ``te``, ``lane``, ``edges``, ``chunks``); chunks get
-        no span of their own, only their blocking host copies are timed,
-        summed into one ``similarity.device_wait`` event per group, and
-        counted under ``similarity.chunks``. Without one nothing is timed.
+        no span of their own, only the window's waits on the device are
+        timed, summed into one ``similarity.device_wait`` event per group,
+        counted under ``similarity.chunks``, and those dispatched before
+        the previous chunk was done under ``similarity.chunks_queued``.
+        Without one nothing is timed.
         """
         if measure not in MEASURES + ("_count",):
             raise ValueError(f"measure must be one of {MEASURES}")
@@ -460,8 +493,7 @@ class SimilarityPlan:
             bounds = np.flatnonzero(np.diff(keys[order])) + 1
             groups = np.split(order, bounds)
 
-        out = np.empty(total, out_dt)
-        shapes = []
+        shapes, runs, nslots = [], [], 0
         for idx in groups:
             cp = int(self.vclass[pu[idx[0]]])
             ct = int(self.vclass[pv[idx[0]]])
@@ -485,28 +517,40 @@ class SimilarityPlan:
                 csize = min(csize, _pow2ceil(
                     pol.profile.probe_interpret_chunk))
             chunks = -(-len(idx) // csize)
-            sentinel_p = self.nbr_blocks[cp].shape[0] - 1
-            wait = 0.0
+            runs.append((idx, cp, ct, sp, st, pe, te, lane, csize, chunks))
+            nslots += chunks * csize
+
+        # every chunk's result lands in its slots of one device buffer, in
+        # group order; one gather puts σ in input order at the end
+        slots = jnp.zeros(_pow2_bucket(nslots), out_dt)
+        where = np.empty(total, np.int32)
+        inflight = collections.deque()
+        base = 0
+        for idx, cp, ct, sp, st, pe, te, lane, csize, chunks in runs:
+            wait, queued = 0.0, 0
             with maybe_span(tracer, "similarity.group", pe=pe, te=te,
                             lane=lane, edges=len(idx), chunks=chunks):
-                for s in range(0, len(idx), csize):
-                    sub = idx[s: s + csize]
-                    pad = csize - len(sub)
-                    args = dict(
-                        p0=_pad1(self.vrow[pu[sub]], pad, sentinel_p),
-                        pt=_pad1(self.vtiles[pu[sub]], pad, 0),
-                        t0=_pad1(self.vrow[pv[sub]], pad,
-                                 self.nbr_blocks[ct].shape[0] - 1),
-                        tt=_pad1(self.vtiles[pv[sub]], pad, 0),
-                        ceu=_pad1(eu[sub].astype(np.int32), pad, 0),
-                        cev=_pad1(ev[sub].astype(np.int32), pad, 0),
-                        cew=_pad1(ew[sub], pad, 0.0),
-                    )
+                # a pow2 count of chunk slots keeps the slicing program's
+                # shapes in the same classes as the chunk programs'
+                staged = jnp.asarray(self._stage(
+                    idx, pu, pv, eu, ev, ew, cp, ct,
+                    _pow2ceil(chunks) * csize))
+                where[idx] = base + np.arange(len(idx), dtype=np.int32)
+                for c in range(chunks):
+                    if len(inflight) == INFLIGHT_CHUNKS:
+                        # bound the queue: wait for the chunk dispatched
+                        # INFLIGHT_CHUNKS before this one, never copy
+                        oldest = inflight.popleft()
+                        if tracer is None:
+                            oldest.block_until_ready()
+                        else:
+                            tw = time.perf_counter()
+                            oldest.block_until_ready()
+                            wait += time.perf_counter() - tw
+                    if tracer is not None and inflight:
+                        queued += not inflight[-1].is_ready()
                     operands = (
-                        jnp.asarray(args["p0"]), jnp.asarray(args["pt"]),
-                        jnp.asarray(args["t0"]), jnp.asarray(args["tt"]),
-                        jnp.asarray(args["ceu"]), jnp.asarray(args["cev"]),
-                        jnp.asarray(args["cew"]),
+                        _chunk_edges(staged, c * csize, size=csize),
                         self.nbr_blocks[cp], self.wgt_blocks[cp],
                         self.nbr_blocks[ct], self.wgt_blocks[ct],
                         self.norms, self.cdeg,
@@ -520,19 +564,15 @@ class SimilarityPlan:
                             be=min(pol.profile.probe_be, csize),
                             bt=pol.profile.probe_bt,
                             interpret=pol.interpret(lane))
-                    if tracer is None:
-                        out[sub] = np.asarray(res)[: len(sub)]
-                        continue
-                    # the host copy blocks on the chunk's device work
-                    tw = time.perf_counter()
-                    host = np.asarray(res)
-                    wait += time.perf_counter() - tw
-                    out[sub] = host[: len(sub)]
+                    slots = _place_chunk(slots, res, base + c * csize)
+                    inflight.append(res)
+            base += chunks * csize
             if tracer is not None:
                 tracer.event("similarity.device_wait", wait)
                 tracer.inc("similarity.chunks", chunks)
+                tracer.inc("similarity.chunks_queued", queued)
         object.__setattr__(self, "last_group_shapes", tuple(shapes))
-        return jnp.asarray(out)
+        return _in_input_order(slots, jnp.asarray(where))
 
 
 def _expand_tile_rows(first: np.ndarray, tiles: np.ndarray) -> np.ndarray:
@@ -615,6 +655,32 @@ def _sigma_epilogue(shared_dot, shared_cnt, eu, ev, ew, norms, cdeg,
     raise ValueError(f"unknown measure {measure!r}")
 
 
+@functools.partial(jax.jit, static_argnames=("size",))
+def _chunk_edges(staged, at, *, size: int):
+    """One chunk's int32[7, size] edge block, sliced on the device from its
+    group's staged int32[7, L] block (:meth:`SimilarityPlan._stage`)."""
+    return jax.lax.dynamic_slice_in_dim(staged, at, size, axis=1)
+
+
+def _edge_operands(edges):
+    """(p0, pt, t0, tt, eu, ev, ew) of a chunk's int32[7, c] edge block;
+    the weights ride bit-cast. One output buffer per slice instead of
+    seven: each costs the host an allocation per chunk."""
+    return (*edges[:6], jax.lax.bitcast_convert_type(edges[6], jnp.float32))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _place_chunk(slots, res, at):
+    """Write one chunk's results into the pass's slot buffer at ``at``."""
+    return jax.lax.dynamic_update_slice_in_dim(slots, res, at, axis=0)
+
+
+@jax.jit
+def _in_input_order(slots, where):
+    """σ in input order: edge i's result sits in slot ``where[i]``."""
+    return slots[where]
+
+
 def _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
                       p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
                       sp: int, st: int, measure: str):
@@ -640,13 +706,12 @@ def _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
 
 
 @functools.partial(jax.jit, static_argnames=("sp", "st", "measure"))
-def _bucket_sims_chunk(p0, pt, t0, tt, eu, ev, ew,
-                       p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
+def _bucket_sims_chunk(edges, p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
                        *, sp: int, st: int, measure: str):
     """One fixed-shape per-(bucket_u, bucket_v) kernel invocation. Every
     shape in the signature is a power of two, so the jit cache is shared
     across graphs and across repeated ``apply_delta`` batches."""
-    return _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
+    return _bucket_sims_core(*_edge_operands(edges),
                              p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
                              sp, st, measure)
 
@@ -679,12 +744,11 @@ def _bucket_sims_pallas_core(p0, pt, t0, tt, eu, ev, ew,
 
 @functools.partial(jax.jit, static_argnames=(
     "sp", "st", "measure", "be", "bt", "interpret"))
-def _bucket_sims_chunk_pallas(p0, pt, t0, tt, eu, ev, ew,
-                              p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
-                              *, sp: int, st: int, measure: str,
+def _bucket_sims_chunk_pallas(edges, p_nbr, p_wgt, t_nbr, t_wgt, norms,
+                              cdeg, *, sp: int, st: int, measure: str,
                               be: int, bt: int, interpret: bool):
     """Jitted :func:`_bucket_sims_pallas_core` for one group chunk."""
-    return _bucket_sims_pallas_core(p0, pt, t0, tt, eu, ev, ew,
+    return _bucket_sims_pallas_core(*_edge_operands(edges),
                                     p_nbr, p_wgt, t_nbr, t_wgt, norms, cdeg,
                                     sp, st, measure, be, bt, interpret)
 

@@ -103,6 +103,72 @@ def test_chunked_bit_identical():
     np.testing.assert_array_equal(a, b)
 
 
+QUEUED_CASES = [
+    (hub_ring_graph(300, 200), "cosine"),
+    (power_law_graph(400, 2.1, seed=12, hub_degree=150), "jaccard"),
+    (power_law_graph(400, 2.1, seed=13, weighted=True, hub_degree=150),
+     "cosine"),
+    (power_law_graph(400, 2.1, seed=14, hub_degree=150), "_count"),
+]
+
+
+@pytest.mark.parametrize("g,measure", QUEUED_CASES)
+def test_queued_chunks_match_one_chunk_per_group(g, measure):
+    """Many chunks per group, queued on the device, give the bits of one
+    chunk per group."""
+    from repro.obs import MetricsRegistry, Tracer
+
+    plan = plan_for(g)
+    args = (g.edge_u, g.nbrs, g.wgts, measure)
+    tr = Tracer(MetricsRegistry())
+    one = plan.edge_sims(*args, tracer=tr)
+    assert {e["attrs"]["chunks"] for e in tr.events("similarity.group")} \
+        == {1}
+    many = plan.edge_sims(*args, chunk=32)
+    assert max(e for *_, e in plan.last_group_shapes) > 8 * 32
+    assert isinstance(many, jax.Array) and many.dtype == one.dtype
+    a, b = np.asarray(many), np.asarray(one)
+    np.testing.assert_array_equal(a.view(np.uint8), b.view(np.uint8))
+
+
+def test_traced_pass_counts_queued_chunks_and_one_wait_per_group():
+    from repro.obs import MetricsRegistry, Tracer
+
+    g = hub_ring_graph(300, 200)
+    plan = plan_for(g)
+    tr = Tracer(MetricsRegistry())
+    plan.edge_sims(g.edge_u, g.nbrs, g.wgts, chunk=32, tracer=tr)
+    snap = tr.registry.snapshot()
+    chunks = snap["counters"]["similarity.chunks"]
+    assert chunks == sum(e["attrs"]["chunks"]
+                         for e in tr.events("similarity.group"))
+    assert chunks > sim_mod.INFLIGHT_CHUNKS      # the window engages
+    assert 0 <= snap["counters"].get("similarity.chunks_queued", 0) < chunks
+    waits = snap["histograms"]["similarity.device_wait"]
+    assert waits["count"] == plan.last_groups
+
+
+def test_pass_makes_no_host_copy(monkeypatch):
+    """σ stays on the device: nothing in the pass copies a device array to
+    the host (the routing reads host inputs only)."""
+    g = power_law_graph(400, 2.1, seed=15, weighted=True, hub_degree=150)
+    plan = plan_for(g)
+    eu, ev, ew = (np.asarray(a) for a in (g.edge_u, g.nbrs, g.wgts))
+    want = np.asarray(plan.edge_sims(eu, ev, ew, chunk=32))
+    host_copy = np.asarray
+
+    def guarded(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            raise AssertionError("the similarity pass copied to the host")
+        return host_copy(a, *args, **kw)
+    monkeypatch.setattr(np, "asarray", guarded)
+    got = plan.edge_sims(eu, ev, ew, chunk=32)
+    monkeypatch.undo()
+    assert isinstance(got, jax.Array)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                  want.view(np.uint8))
+
+
 def test_triangle_counts_exact():
     g = power_law_graph(90, 2.1, seed=7, hub_degree=40)
     import jax.numpy as jnp
@@ -151,13 +217,15 @@ def test_jit_cache_hoisted_across_apply_delta_batches():
 
     idx, g, _ = apply_delta(idx, g, ins)          # warm the caches
     idx, g, _ = apply_delta(idx, g, dels)
-    warm = sim_mod._bucket_sims_chunk._cache_size()
+    progs = (sim_mod._bucket_sims_chunk, sim_mod._chunk_edges,
+             sim_mod._place_chunk)
+    warm = [p._cache_size() for p in progs]
     for _ in range(3):
         idx, g, info = apply_delta(idx, g, ins)
         assert info.n_frontier > 0
         idx, g, info = apply_delta(idx, g, dels)
         assert info.n_frontier > 0
-    assert sim_mod._bucket_sims_chunk._cache_size() == warm
+    assert [p._cache_size() for p in progs] == warm
 
 
 def test_plan_cache_reuses_per_graph_object():
@@ -242,8 +310,8 @@ if hypothesis is not None:
         weighted = draw(st.booleans())
         if not pairs:
             pairs = [(0, 1)]
-        w = (draw(st.lists(st.floats(0.1, 1.0, allow_nan=False,
-                                     width=32),
+        w = (draw(st.lists(st.floats(0.10000000149011612, 1.0,
+                                     allow_nan=False, width=32),
                            min_size=len(pairs), max_size=len(pairs)))
              if weighted else None)
         g = from_edge_list(n, np.asarray(pairs, np.int64),

@@ -247,7 +247,8 @@ if hypothesis is not None:
         if draw(st.booleans()):                        # force a hub at 0
             pairs += [(0, v) for v in range(1, n)]
         weighted = draw(st.booleans())
-        w = (draw(st.lists(st.floats(0.1, 1.0, allow_nan=False, width=32),
+        w = (draw(st.lists(st.floats(0.10000000149011612, 1.0,
+                                     allow_nan=False, width=32),
                            min_size=len(pairs), max_size=len(pairs)))
              if weighted else None)
         g = from_edge_list(n, np.asarray(pairs, np.int64),
