@@ -164,13 +164,15 @@ class ApproxIndexBuilder:
     def provenance(self) -> IndexProvenance:
         return IndexProvenance.for_params(self.params)
 
-    def similarities(self, g: CSRGraph) -> jax.Array:
-        """The sketched per-half-edge σ̂ (exact on §6.3 heuristic edges)."""
+    def similarities(self, g: CSRGraph, *, tracer=None) -> jax.Array:
+        """The sketched per-half-edge σ̂ (exact on §6.3 heuristic edges);
+        ``tracer`` traces the pass (:func:`approximate_similarities`)."""
         p = self.params
         return approximate_similarities(
             g, measure=self.measure, method=p.method, samples=p.samples,
             key=jax.random.PRNGKey(p.seed),
-            degree_heuristic=p.degree_heuristic, policy=self.policy)
+            degree_heuristic=p.degree_heuristic, policy=self.policy,
+            tracer=tracer)
 
     def build(self, g: CSRGraph, *,
               tracer=None) -> Tuple[ScanIndex, IndexProvenance]:
@@ -178,12 +180,16 @@ class ApproxIndexBuilder:
 
         ``tracer`` (a :class:`repro.obs.Tracer`) wraps the construction in
         an ``index.approx_build`` span so approximate-build latency lands
-        in the same histogram taxonomy as the rest of the serve stack.
+        in the same histogram taxonomy as the rest of the serve stack;
+        inside it the ``lsh.*`` spans of the sketched pass and the
+        ``index.build`` of the orders. Without one nothing is timed.
         """
         p = self.params
         with maybe_span(tracer, "index.approx_build", method=p.method,
                         samples=p.samples, seed=p.seed, n=g.n, m=g.m):
-            index = build_index(g, self.measure, sims=self.similarities(g))
+            index = build_index(g, self.measure,
+                                sims=self.similarities(g, tracer=tracer),
+                                tracer=tracer)
         return index, self.provenance
 
 

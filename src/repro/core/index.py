@@ -142,7 +142,8 @@ def build_index(
 
     ``tracer`` (a :class:`repro.obs.Tracer`) wraps the build in an
     ``index.build`` span holding ``similarity.pass`` (when σ is computed
-    here; the exact pass traces its plan, routing and groups),
+    here; the exact pass traces its plan, routing and groups, the LSH pass
+    its ``lsh.*`` stages),
     ``index.orders`` (the global sorts, through the host read of their
     result) and ``index.co_offsets``. Without one nothing is timed."""
     with maybe_span(tracer, "index.build", n=g.n, m=g.m):
@@ -161,6 +162,7 @@ def build_index(
                         key=(key if key is not None
                              else jax.random.PRNGKey(0)),
                         degree_heuristic=degree_heuristic,
+                        tracer=tracer,
                     )
         sims = jnp.clip(sims.astype(jnp.float32), 0.0, 1.0)
 

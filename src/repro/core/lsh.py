@@ -28,6 +28,7 @@ the exact pass never touches a hub-width kernel.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Optional
 
 import jax
@@ -38,6 +39,7 @@ from repro.core.graph import CSRGraph
 from repro.core import similarity as sim_mod
 from repro.kernels.hamming import hamming_sigma
 from repro.kernels.ref import hamming_distance_ref
+from repro.obs.trace import maybe_span
 
 
 # --------------------------------------------------------------------------
@@ -205,6 +207,7 @@ def approximate_similarities(
     key: Optional[jax.Array] = None,
     degree_heuristic: bool = True,
     policy=None,
+    tracer=None,
 ) -> jax.Array:
     """σ̂ per half-edge. Sketch *construction* is always the chunk-invariant
     sparse jnp path (its bits define the approximate fingerprint); the
@@ -213,7 +216,19 @@ def approximate_similarities(
     reproduce the ``ref`` comparison bit-for-bit on every backend (each
     lane yields the integer-exact XOR/popcount distance, and all of them
     share one jitted cos epilogue), so lane choice never moves a
-    fingerprint."""
+    fingerprint.
+
+    With a ``tracer`` (:class:`repro.obs.Tracer`) the pass is four spans:
+    ``lsh.sketch`` (every sketch chunk, to its device result),
+    ``lsh.compare`` (the endpoint gathers and the comparison),
+    ``lsh.exact_subset`` (the heuristic's mask, the plan and the exact
+    pass on its ``edges`` half-edges, which trace themselves as in
+    :meth:`~repro.core.similarity.SimilarityPlan.edge_sims`) and
+    ``lsh.merge`` (exact σ written into σ̂, the clip). Each span ends by
+    waiting for its device results, timed as an ``lsh.device_wait``
+    event; the half-edges given σ̂ and exact σ count under
+    ``lsh.sketched_edges`` and ``lsh.exact_edges``. Without one nothing
+    is timed and nothing waits."""
     from repro.backend.policy import default_policy
 
     pol = policy if policy is not None else default_policy()
@@ -223,45 +238,78 @@ def approximate_similarities(
         if measure != "cosine":
             raise ValueError("simhash approximates cosine similarity")
         from repro.kernels import ops
-        sk = simhash_sketches(g, samples, key)
-        approx = ops.simhash_edge_similarity_kernel(
-            sk, g.edge_u, g.nbrs, samples, policy=pol,
-            lane=pol.lane("hamming"))
+
+        def sketch():
+            return simhash_sketches(g, samples, key)
+
+        def compare(sk):
+            return ops.simhash_edge_similarity_kernel(
+                sk, g.edge_u, g.nbrs, samples, policy=pol,
+                lane=pol.lane("hamming"))
         thr = samples
     elif method in ("minhash", "kpartition"):
         if measure != "jaccard":
             raise ValueError("minhash approximates jaccard similarity")
-        if method == "minhash":
-            sk = minhash_sketches(g, samples, key)
-            approx = minhash_edge_similarity(sk, g.edge_u, g.nbrs)
-        else:
-            sk = kpartition_sketches(g, samples, key)
-            approx = kpartition_edge_similarity(sk, g.edge_u, g.nbrs)
+        sketch_fn, compare_fn = (
+            (minhash_sketches, minhash_edge_similarity) if method == "minhash"
+            else (kpartition_sketches, kpartition_edge_similarity))
+
+        def sketch():
+            return sketch_fn(g, samples, key)
+
+        def compare(sk):
+            return compare_fn(sk, g.edge_u, g.nbrs)
         thr = (3 * samples) // 2
     else:
         raise ValueError(f"unknown LSH method {method!r}")
 
-    if not degree_heuristic:
-        return jnp.clip(approx, 0.0, 1.0)
+    with maybe_span(tracer, "lsh.sketch", method=method, samples=samples):
+        sk = _wait(tracer, sketch())
+    with maybe_span(tracer, "lsh.compare", edges=g.m2):
+        approx = _wait(tracer, compare(sk))
 
     # §6.3: exact σ for edges where either endpoint is low-degree; the exact
     # pass runs only on the compacted subset (real work saving, not a mask)
     # through the bucketed plan — each exact edge probes its low-degree side,
     # so the subset routes to the small degree-class kernels only.
-    cdeg = np.asarray(g.closed_degrees())
-    eu_h, ev_h = np.asarray(g.edge_u), np.asarray(g.nbrs)
-    high = cdeg > thr
-    use_exact = ~(high[eu_h] & high[ev_h])
-    idx = np.nonzero(use_exact)[0]
-    if len(idx) == 0:
-        return jnp.clip(approx, 0.0, 1.0)   # pure-LSH path: no plan needed
-    exact_subset = sim_mod.plan_for(g).edge_sims(
-        eu_h[idx],
-        ev_h[idx],
-        np.asarray(g.wgts)[idx],
-        measure=measure,
-        policy=pol,
-    )
-    out = np.asarray(approx, dtype=np.float32).copy()
-    out[idx] = np.asarray(exact_subset)
-    return jnp.clip(jnp.asarray(out), 0.0, 1.0)
+    idx = np.zeros(0, np.int64)
+    exact_subset = None
+    if degree_heuristic:
+        with maybe_span(tracer, "lsh.exact_subset") as span:
+            cdeg = np.asarray(g.closed_degrees())
+            eu_h, ev_h = np.asarray(g.edge_u), np.asarray(g.nbrs)
+            high = cdeg > thr
+            idx = np.nonzero(~(high[eu_h] & high[ev_h]))[0]
+            if span is not None:
+                span.set(edges=len(idx))
+            if len(idx):
+                exact_subset = sim_mod.plan_for(g, tracer=tracer).edge_sims(
+                    eu_h[idx],
+                    ev_h[idx],
+                    np.asarray(g.wgts)[idx],
+                    measure=measure,
+                    policy=pol,
+                    tracer=tracer,
+                )
+                _wait(tracer, exact_subset)
+    if tracer is not None:
+        tracer.inc("lsh.sketched_edges", g.m2 - len(idx))
+        tracer.inc("lsh.exact_edges", len(idx))
+    with maybe_span(tracer, "lsh.merge", edges=len(idx)):
+        if exact_subset is None:            # pure-LSH path: no plan needed
+            out = jnp.clip(approx, 0.0, 1.0)
+        else:
+            merged = np.asarray(approx, dtype=np.float32).copy()
+            merged[idx] = np.asarray(exact_subset)
+            out = jnp.clip(jnp.asarray(merged), 0.0, 1.0)
+        return _wait(tracer, out)
+
+
+def _wait(tracer, x):
+    """``x`` once the device has it, the wait timed as an
+    ``lsh.device_wait`` event; without a tracer ``x`` as it is."""
+    if tracer is not None:
+        t0 = time.perf_counter()
+        jax.block_until_ready(x)
+        tracer.event("lsh.device_wait", time.perf_counter() - t0)
+    return x
