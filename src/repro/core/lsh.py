@@ -20,7 +20,8 @@
 Degree heuristic (§6.3): approximate only edges whose *both* endpoints have
 closed degree above a threshold (k for cosine, 3k/2 for Jaccard); all other
 edges get exact similarities, computed only on that compacted subset via
-the degree-bucketed engine (:class:`repro.core.similarity.SimilarityPlan`).
+the degree-bucketed engine (:class:`repro.core.similarity.SimilarityPlan`),
+once per undirected edge.
 The heuristic and the bucketed layout compose naturally: every exact edge
 has a low-degree endpoint, so its probe routes to a small degree class —
 the exact pass never touches a hub-width kernel.
@@ -222,8 +223,9 @@ def approximate_similarities(
     ``lsh.sketch`` (every sketch chunk, to its device result),
     ``lsh.compare`` (the endpoint gathers and the comparison),
     ``lsh.exact_subset`` (the heuristic's mask, the plan and the exact
-    pass on its ``edges`` half-edges, which trace themselves as in
-    :meth:`~repro.core.similarity.SimilarityPlan.edge_sims`) and
+    pass, which computes σ on its ``edges`` upper half-edges and gives
+    each twin the same bits, tracing itself as in
+    :meth:`~repro.core.similarity.SimilarityPlan.half_edge_sims`) and
     ``lsh.merge`` (exact σ written into σ̂, the clip). Each span ends by
     waiting for its device results, timed as an ``lsh.device_wait``
     event; the half-edges given σ̂ and exact σ count under
@@ -279,18 +281,15 @@ def approximate_similarities(
             cdeg = np.asarray(g.closed_degrees())
             eu_h, ev_h = np.asarray(g.edge_u), np.asarray(g.nbrs)
             high = cdeg > thr
+            # the mask is symmetric, so the subset is closed under twins:
+            # σ is computed on its upper half only
             idx = np.nonzero(~(high[eu_h] & high[ev_h]))[0]
             if span is not None:
-                span.set(edges=len(idx))
+                span.set(edges=len(idx) // 2)
             if len(idx):
-                exact_subset = sim_mod.plan_for(g, tracer=tracer).edge_sims(
-                    eu_h[idx],
-                    ev_h[idx],
-                    np.asarray(g.wgts)[idx],
-                    measure=measure,
-                    policy=pol,
-                    tracer=tracer,
-                )
+                exact_subset = sim_mod.plan_for(
+                    g, tracer=tracer).half_edge_sims(
+                        g, idx, measure=measure, policy=pol, tracer=tracer)
                 _wait(tracer, exact_subset)
     if tracer is not None:
         tracer.inc("lsh.sketched_edges", g.m2 - len(idx))

@@ -1,9 +1,10 @@
 """Exact structural-similarity computation (paper §4.1.1, Algorithm 1).
 
-σ(u,v) is computed for every half-edge by a **degree-bucketed similarity
-engine**. Real-world graphs are power-law: one hub vertex used to inflate
-the single dense ``[n, Δ]`` padded neighbor matrix to O(n·Δ) memory and
-every edge probe to O(Δ) work. The bucketed layout kills that skew
+σ(u,v) is computed once per undirected edge by a **degree-bucketed
+similarity engine**, and the half-edge (v,u) is given the same bits.
+Real-world graphs are power-law: one hub vertex used to inflate the single
+dense ``[n, Δ]`` padded neighbor matrix to O(n·Δ) memory and every edge
+probe to O(Δ) work. The bucketed layout kills that skew
 pathology (the GPUSCAN++ work-partitioning insight, applied to the padded
 operand layout):
 
@@ -44,9 +45,14 @@ operand layout):
 Entry points:
 
 * ``compute_similarities`` — σ for every half-edge (production path).
-* ``edge_similarities_subset`` — σ for an arbitrary edge subset (the §6.3
-  degree-heuristic exact pass under LSH, and the incremental-update
-  frontier recompute). Group chunks are padded to power-of-two shapes so
+* ``SimilarityPlan.half_edge_sims`` — σ for a set of half-edges closed
+  under twins (all m2, or the §6.3 degree-heuristic exact subset under
+  LSH): only the upper half-edges (u < v) run through the chunk programs,
+  each lower one reads its twin's result (σ(u,v) and σ(v,u) are the same
+  bits: one route, one probe, a commutative epilogue on equal weights).
+* ``edge_similarities_subset`` / ``SimilarityPlan.edge_sims`` — σ for an
+  arbitrary edge subset (the incremental-update frontier recompute),
+  every edge computed. Group chunks are padded to power-of-two shapes so
   repeated calls reuse one compiled kernel per (class pair).
 * ``SimilarityPlan`` — the bucketed operands for one graph (blocks, vertex
   routing tables, norms); build once via :func:`plan_for` and reuse.
@@ -474,7 +480,9 @@ class SimilarityPlan:
         timed, summed into one ``similarity.device_wait`` event per group,
         counted under ``similarity.chunks``, and those dispatched before
         the previous chunk was done under ``similarity.chunks_queued``.
-        Without one nothing is timed.
+        The edges a chunk program computed count under
+        ``similarity.sigma_computed``. Without a tracer nothing is timed
+        or counted.
         """
         if measure not in MEASURES + ("_count",):
             raise ValueError(f"measure must be one of {MEASURES}")
@@ -571,8 +579,59 @@ class SimilarityPlan:
                 tracer.event("similarity.device_wait", wait)
                 tracer.inc("similarity.chunks", chunks)
                 tracer.inc("similarity.chunks_queued", queued)
+        if tracer is not None:
+            tracer.inc("similarity.sigma_computed", total)
         object.__setattr__(self, "last_group_shapes", tuple(shapes))
         return _in_input_order(slots, jnp.asarray(where))
+
+    def half_edge_sims(
+        self,
+        g: CSRGraph,
+        sel=None,
+        measure: str = "cosine",
+        chunk: int = 1 << 16,
+        policy: Optional[ExecutionPolicy] = None,
+        tracer=None,
+    ) -> jax.Array:
+        """σ for the half-edges ``sel`` of this plan's graph ``g``, in
+        ``sel``'s order: ``sel`` is a sorted index array into ``g``'s CSR
+        slots (``None``: all m2) that holds the twin (v, u) of each of its
+        half-edges (u, v).
+
+        σ(u, v) and σ(v, u) are the same bits: both route to one probe
+        side, one target side and one group, the weights of twins are
+        equal, and the epilogue's product of norms and sum of closed
+        degrees commute. So only the upper half-edges (u < v) go through
+        :meth:`edge_sims`; each lower one reads its twin's result in one
+        gather, its twin found by a stable device sort of the upper
+        half-edges by their larger endpoint. A set in which not exactly
+        half the half-edges are upper is not closed under twins and
+        raises ``ValueError``; the check is necessary, not sufficient, so
+        callers pass sets closed by construction.
+
+        With a ``tracer`` the pass is traced as in :meth:`edge_sims`, and
+        the half-edges given their twin's σ count under
+        ``similarity.sigma_twinned``."""
+        if measure not in MEASURES:
+            raise ValueError(f"measure must be one of {MEASURES}")
+        eu, ev, ew = (np.asarray(a) for a in (g.edge_u, g.nbrs, g.wgts))
+        if sel is not None:
+            sel = np.asarray(sel, dtype=np.int64)
+            eu, ev, ew = eu[sel], ev[sel], ew[sel]
+        total = len(eu)
+        up = np.flatnonzero(eu < ev)
+        if 2 * len(up) != total:
+            raise ValueError(
+                f"half_edge_sims: {len(up)} of {total} half-edges have "
+                "u < v; a set closed under twins has exactly half")
+        if total == 0:
+            return jnp.zeros((0,), jnp.float32)
+        sims = self.edge_sims(eu[up], ev[up], ew[up], measure, chunk,
+                              policy, tracer)
+        if tracer is not None:
+            tracer.inc("similarity.sigma_twinned", total - len(up))
+        return _twinned_order(sims, jnp.asarray(ev[up], dtype=jnp.int32),
+                              jnp.asarray(eu < ev))
 
 
 def _expand_tile_rows(first: np.ndarray, tiles: np.ndarray) -> np.ndarray:
@@ -679,6 +738,19 @@ def _place_chunk(slots, res, at):
 def _in_input_order(slots, where):
     """σ in input order: edge i's result sits in slot ``where[i]``."""
     return slots[where]
+
+
+@jax.jit
+def _twinned_order(sims, ev_up, upper):
+    """σ of a set of half-edges closed under twins, in its order, from
+    ``sims``, σ of its upper half-edges (``upper``: u < v) in their order.
+    In order, the lower half-edges (u, v) list their twins (v, u) by
+    (u, v): the upper half-edges sorted stably by their larger endpoint
+    ``ev_up``, one device sort of half the set."""
+    twin = jnp.argsort(ev_up, stable=True)
+    k_up = jnp.cumsum(upper) - 1
+    k_low = jnp.maximum(jnp.cumsum(~upper) - 1, 0)
+    return sims[jnp.where(upper, k_up, twin[k_low])]
 
 
 def _bucket_sims_core(p0, pt, t0, tt, eu, ev, ew,
@@ -837,13 +909,12 @@ def edge_similarities_subset(
     *,
     tracer=None,
 ) -> jax.Array:
-    """Exact σ for an arbitrary subset of edges (endpoint arrays).
-
-    Used for the full-graph pass, the §6.3 degree-heuristic compacted
-    exact pass under LSH, and the incremental-update frontier recompute.
-    Edges route to per-degree-class kernels with power-of-two chunk
-    shapes, so repeated calls (e.g. update batches at the same pow2 size)
-    reuse one compiled function per class pair. ``tracer`` traces the
+    """Exact σ for an arbitrary subset of edges (endpoint arrays), every
+    edge computed (a set closed under twins takes
+    :meth:`SimilarityPlan.half_edge_sims`, which computes half). Edges
+    route to per-degree-class kernels with power-of-two chunk shapes, so
+    repeated calls (e.g. update batches at the same pow2 size) reuse one
+    compiled function per class pair. ``tracer`` traces the
     plan build and the pass (:meth:`SimilarityPlan.edge_sims`).
     """
     if measure not in MEASURES:
@@ -856,10 +927,12 @@ def compute_similarities(
     g: CSRGraph, measure: str = "cosine", chunk: int = 1 << 16, *,
     tracer=None,
 ) -> jax.Array:
-    """Exact σ for every half-edge, float32[m2]. Host-orchestrated routing
-    over the degree-bucketed engine."""
-    return edge_similarities_subset(g, g.edge_u, g.nbrs, g.wgts, measure,
-                                    chunk, tracer=tracer)
+    """Exact σ for every half-edge, float32[m2]: host-orchestrated routing
+    over the degree-bucketed engine, σ computed once per undirected edge
+    (:meth:`SimilarityPlan.half_edge_sims`) and given to both halves.
+    ``tracer`` traces the plan build and the pass."""
+    return plan_for(g, tracer=tracer).half_edge_sims(
+        g, None, measure, chunk, tracer=tracer)
 
 
 def triangle_counts(g: CSRGraph) -> jax.Array:

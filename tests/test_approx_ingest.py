@@ -132,11 +132,49 @@ def test_approx_spans_nest_and_counters_add_up_to_m2(heuristic):
     assert h["lsh.device_wait"]["count"] == len(stages)
     assert h["lsh.device_wait"]["sum"] <= sum(h[s]["sum"] for s in stages)
     if heuristic:
+        # σ is computed on one half of each twin pair of the subset
         (sub,) = tr.events("lsh.exact_subset")
-        assert sub["attrs"]["edges"] == exact
+        assert sub["attrs"]["edges"] == exact // 2
         groups = tr.events("similarity.group")
         assert groups and all(e["parent_id"] == sub["span_id"]
                               for e in groups)
-        assert sum(e["attrs"]["edges"] for e in groups) == exact
+        assert sum(e["attrs"]["edges"] for e in groups) == exact // 2
+        assert c["similarity.sigma_computed"] == exact // 2
+        assert c["similarity.sigma_twinned"] == exact // 2
     else:
         assert "similarity.group" not in h
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_served_exact_subset_has_the_bits_of_every_half_computed(
+        tmp_path, weighted):
+    """The exact subset is closed under twins and its σ computed once per
+    edge: the served σ̂ there equals ``edge_sims`` over both halves of
+    the subset, bit for bit, and the two counters still add up to m2."""
+    g = hub_graph(seed=7)
+    if weighted:
+        eu, ev = np.asarray(g.edge_u), np.asarray(g.nbrs)
+        up = eu < ev
+        w = np.random.default_rng(7).uniform(0.1, 1.0, up.sum())
+        g = from_edge_list(g.n, np.stack([eu[up], ev[up]], 1),
+                           w.astype(np.float32))
+    samples = 32
+    svc = LiveIndexService(str(tmp_path), config=EngineConfig(max_batch=8))
+    svc.register_approximate("web", g, params=ApproxParams(
+        method="simhash", samples=samples, seed=2))
+    served = np.asarray(svc.index("web").edge_sims)
+    exact = np.flatnonzero(~sketched_mask(g, samples))
+    eu, ev = np.asarray(g.edge_u), np.asarray(g.nbrs)
+    key = np.minimum(eu, ev).astype(np.int64) * g.n + np.maximum(eu, ev)
+    up, down = exact[eu[exact] < ev[exact]], exact[eu[exact] > ev[exact]]
+    assert 0 < len(up) == len(down)
+    np.testing.assert_array_equal(np.sort(key[up]), np.sort(key[down]))
+    want = np.asarray(jax.numpy.clip(sim_mod.plan_for(g).edge_sims(
+        eu[exact], ev[exact], np.asarray(g.wgts)[exact]), 0.0, 1.0))
+    np.testing.assert_array_equal(served[exact].view(np.uint8),
+                                  want.view(np.uint8))
+    c = svc.engine.registry.snapshot()["counters"]
+    assert c["lsh.exact_edges"] == len(exact)
+    assert c["lsh.sketched_edges"] + c["lsh.exact_edges"] == g.m2
+    assert c["similarity.sigma_computed"] == c["similarity.sigma_twinned"] \
+        == len(exact) // 2

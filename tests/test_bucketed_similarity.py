@@ -103,6 +103,98 @@ def test_chunked_bit_identical():
     np.testing.assert_array_equal(a, b)
 
 
+def _isolated_vertices_graph():
+    """Two triangles and a path among 40 vertices: most have no edge."""
+    return from_edge_list(40, np.array([(3, 7), (7, 9), (3, 9), (20, 21),
+                                        (21, 22), (20, 22), (30, 31),
+                                        (31, 35)]))
+
+
+TWINNED_CASES = {
+    # hubs wider than HUB_TILE: their rows split into tile rows
+    "hub_tiles": (hub_ring_graph(2600, 2300), "cosine", 1 << 16),
+    "weighted": (random_graph(64, 7.0, seed=2, weighted=True), "cosine",
+                 1 << 16),
+    "jaccard": (power_law_graph(150, 2.1, seed=3, hub_degree=64), "jaccard",
+                1 << 16),
+    "isolated": (_isolated_vertices_graph(), "cosine", 1 << 16),
+    "empty": (from_edge_list(5, np.zeros((0, 2), np.int64)), "cosine",
+              1 << 16),
+    "multi_chunk": (power_law_graph(400, 2.1, seed=13, weighted=True,
+                                    hub_degree=150), "cosine", 32),
+}
+
+
+@pytest.mark.parametrize("case", list(TWINNED_CASES))
+def test_twinned_pass_matches_every_half_edge_computed(case):
+    """σ computed on the upper half-edges and given to each twin is, bit
+    for bit, σ computed on all m2 half-edges."""
+    g, measure, chunk = TWINNED_CASES[case]
+    if case == "hub_tiles":
+        assert int(np.asarray(g.degrees()).max()) > sim_mod.HUB_TILE
+    plan = plan_for(g)
+    want = np.asarray(plan.edge_sims(g.edge_u, g.nbrs, g.wgts, measure,
+                                     chunk))
+    got = np.asarray(plan.half_edge_sims(g, None, measure, chunk))
+    assert got.shape == want.shape == (g.m2,)
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    np.testing.assert_array_equal(
+        np.asarray(compute_similarities(g, measure, chunk)).view(np.uint8),
+        want.view(np.uint8))
+    if case == "hub_tiles":
+        assert int(plan.vtiles.max()) > 1               # tile rows engaged
+
+
+def test_twinned_pass_on_a_twin_closed_subset_keeps_its_order():
+    """A subset closed under twins (both halves of random undirected
+    edges) gets σ in its own order, the bits of every half computed."""
+    g = power_law_graph(200, 2.1, seed=9, weighted=True, hub_degree=80)
+    eu, ev = np.asarray(g.edge_u), np.asarray(g.nbrs)
+    rng = np.random.default_rng(4)
+    pick = rng.random(g.m2) < 0.4
+    key = np.minimum(eu, ev) * g.n + np.maximum(eu, ev)
+    sel = np.flatnonzero(np.isin(key, key[pick & (eu < ev)]))
+    assert 0 < len(sel) < g.m2
+    plan = plan_for(g)
+    want = np.asarray(plan.edge_sims(eu[sel], ev[sel],
+                                     np.asarray(g.wgts)[sel], "cosine"))
+    got = np.asarray(plan.half_edge_sims(g, sel, "cosine"))
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+def test_twinned_pass_refuses_a_set_not_closed_under_twins():
+    g = random_graph(60, 5.0, seed=3)
+    eu, ev = np.asarray(g.edge_u), np.asarray(g.nbrs)
+    with pytest.raises(ValueError, match="closed under twins"):
+        plan_for(g).half_edge_sims(g, np.flatnonzero(eu < ev))
+    with pytest.raises(ValueError, match="closed under twins"):
+        plan_for(g).half_edge_sims(g, np.flatnonzero(eu > ev)[:-1])
+
+
+def test_traced_build_counts_each_sigma_once_and_untraced_counts_nothing(
+        monkeypatch):
+    from repro.obs import MetricsRegistry, Tracer
+
+    g = power_law_graph(300, 2.1, seed=15, hub_degree=100)
+    tr = Tracer(MetricsRegistry())
+    build_index(g, tracer=tr)
+    c = tr.registry.snapshot()["counters"]
+    assert c["similarity.sigma_computed"] == g.m
+    assert c["similarity.sigma_twinned"] == g.m
+    # untraced: the process-default registry, which counts the lanes of
+    # every build, gains no σ counter, and no tracer is asked to count
+    from repro.backend.policy import default_policy
+
+    def no_count(*a, **k):
+        raise AssertionError("an untraced build counted")
+    monkeypatch.setattr(Tracer, "inc", no_count)
+    before = default_policy().registry.snapshot()["counters"]
+    build_index(power_law_graph(300, 2.1, seed=15, hub_degree=100))
+    after = default_policy().registry.snapshot()["counters"]
+    assert {k: v for k, v in after.items() if k.startswith("similarity.")} \
+        == {k: v for k, v in before.items() if k.startswith("similarity.")}
+
+
 QUEUED_CASES = [
     (hub_ring_graph(300, 200), "cosine"),
     (power_law_graph(400, 2.1, seed=12, hub_degree=150), "jaccard"),
