@@ -10,8 +10,8 @@ per-index cache partitions and sweep-ahead warming.
 # devices BEFORE jax's backend initializes (importing is fine, device use
 # is not; harmless when real accelerators exist). Host compute is split
 # 8 ways for the WHOLE demo, so the timings printed below illustrate the
-# flow, not single-device performance — benchmarks/bench_serve.py is the
-# measured story.
+# flow, not single-device performance; the chip benchmark under bench/
+# measures that.
 from repro.core.distributed import force_host_devices
 
 force_host_devices(8)

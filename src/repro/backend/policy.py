@@ -10,8 +10,8 @@ hard-coded at each call site. The policy centralizes all of it:
   import (as the subprocess mesh tests do) is honored, and importing this
   module never initializes the jax backend;
 * **a kernel registry** — each hot op (``bucket_probe``, ``simhash``,
-  ``hamming``, ``triangle_count``, plus ``attention`` and the pure-jnp
-  ``query`` path) maps to its available lanes: ``ref`` (pure-jnp oracle),
+  ``hamming``, ``triangle_count``, and the pure-jnp ``query`` path) maps
+  to its available lanes: ``ref`` (pure-jnp oracle),
   ``pallas-interpret`` (kernel body emulated on host), and
   ``pallas-compiled`` (real accelerator dispatch);
 * **calibrated thresholds** — an :class:`~repro.backend.profile
@@ -54,7 +54,6 @@ OPS = {
     "simhash": LANES,
     "hamming": LANES,
     "triangle_count": LANES,
-    "attention": LANES,
     "query": (LANE_REF,),       # (μ, ε) sweep path is pure jnp today
 }
 

@@ -12,7 +12,6 @@ from repro.core.similarity import (
     SimilarityPlan,
     compute_similarities,
     compute_similarities_dense,
-    compute_similarities_densepad,
     edge_similarities_subset,
     plan_for,
     triangle_counts,

@@ -66,9 +66,6 @@ Entry points:
   small integer, exact in float32 under any reduction order, so the
   bucketed engine is **bit-identical** to this oracle; weighted sums are
   order-sensitive at the ULP level (asserted in tests).
-* ``compute_similarities_densepad`` — the legacy O(n·Δ) dense-padded path,
-  kept as the benchmark baseline (``benchmarks/bench_index_construction``
-  measures bucketed vs dense-padded on skewed graphs).
 
 Per-group lane choice goes through :class:`repro.backend.ExecutionPolicy`
 (``plan.edge_sims(..., policy=...)``): the ``ref`` lane is the jnp
@@ -126,9 +123,6 @@ CHUNK_ELEMS = 1 << 22
 INFLIGHT_CHUNKS = 8
 # fewest edges per compiled bucket_probe chunk: one full 128-lane row
 KERNEL_LANE_EDGES = 128
-
-# legacy dense-padded quantum (kept for the benchmark baseline path)
-PAD_WIDTH_QUANTUM = 8
 
 
 def _routing_tables(deg: np.ndarray, n: int, hub_tile: int):
@@ -961,106 +955,3 @@ def compute_similarities_dense(g: CSRGraph, measure: str = "cosine") -> jax.Arra
     adj_c = to_dense(g, closed=True, weighted=weighted)
     return _dense_sims(adj_c, g.edge_u, g.nbrs, g.closed_degrees(), measure)
 
-
-# ---------------------------------------------------------------------------
-# legacy dense-padded path — benchmark baseline only
-# ---------------------------------------------------------------------------
-def padded_width(g: CSRGraph) -> int:
-    """[legacy baseline] Global padded row width M: max open degree rounded
-    up to ``PAD_WIDTH_QUANTUM``. One hub inflates M (and the O(n·M) padded
-    matrices below) for every vertex — the skew pathology the bucketed
-    engine exists to remove."""
-    deg = np.asarray(g.degrees())
-    m = int(deg.max()) if len(deg) else 1
-    m = max(m, 1)
-    return -(-m // PAD_WIDTH_QUANTUM) * PAD_WIDTH_QUANTUM
-
-
-def padded_neighbors(g: CSRGraph) -> Tuple[jax.Array, jax.Array, int]:
-    """[legacy baseline] Dense padded (nbr_mat[n, M], wgt_mat[n, M], M).
-    Pad id = n (sorts last). O(n·M) memory — superseded by
-    :class:`SimilarityPlan`; retained for the construction benchmark's
-    dense-vs-bucketed comparison."""
-    m = padded_width(g)
-    offsets = np.asarray(g.offsets)
-    nbr_mat = np.full((g.n, m), g.n, dtype=np.int32)
-    wgt_mat = np.zeros((g.n, m), dtype=np.float32)
-    if g.m2:
-        eu = np.asarray(g.edge_u)
-        pos = np.arange(g.m2, dtype=np.int64) - offsets[eu]
-        nbr_mat[eu, pos] = np.asarray(g.nbrs)
-        wgt_mat[eu, pos] = np.asarray(g.wgts)
-    return jnp.asarray(nbr_mat), jnp.asarray(wgt_mat), m
-
-
-def densepad_operand_bytes(g: CSRGraph) -> int:
-    """[legacy baseline] Peak similarity-operand bytes of the dense-padded
-    layout: the two O(n·M) matrices plus norms/closed degrees."""
-    return g.n * padded_width(g) * (4 + 4) + 8 * g.n
-
-
-@functools.partial(jax.jit, static_argnames=("measure",))
-def _edge_sims_chunk(
-    eu: jax.Array,        # int32[c] chunk of half-edge sources
-    ev: jax.Array,        # int32[c] chunk of half-edge targets
-    ew: jax.Array,        # float32[c] chunk of half-edge weights
-    nbr_mat: jax.Array,   # int32[n, M]
-    wgt_mat: jax.Array,   # float32[n, M]
-    norms: jax.Array,     # float32[n]
-    cdeg: jax.Array,      # int32[n] closed degrees
-    measure: str,
-) -> jax.Array:
-    """[legacy baseline] σ for one chunk of half-edges via vectorized
-    binary search over the global-width padded rows."""
-    rows_u = nbr_mat[eu]                      # [c, M] probe row
-    w_u = wgt_mat[eu]                         # [c, M]
-    rows_v = nbr_mat[ev]                      # [c, M] target row (sorted)
-    w_v = wgt_mat[ev]                         # [c, M]
-
-    pos = jax.vmap(jnp.searchsorted)(rows_v, rows_u)       # [c, M]
-    pos_c = jnp.minimum(pos, rows_v.shape[1] - 1)
-    hit = jnp.take_along_axis(rows_v, pos_c, axis=1) == rows_u
-    hit &= rows_u < nbr_mat.shape[0]                        # mask row padding
-    w_match = jnp.take_along_axis(w_v, pos_c, axis=1)
-    shared_dot = jnp.sum(jnp.where(hit, w_u * w_match, 0.0), axis=1)
-    shared_cnt = jnp.sum(hit, axis=1)
-
-    if measure == "cosine":
-        closed_dot = shared_dot + 2.0 * ew
-        return closed_dot / (norms[eu] * norms[ev])
-    elif measure == "jaccard":
-        c = shared_cnt.astype(jnp.float32) + 2.0            # + {u, v}
-        union = cdeg[eu].astype(jnp.float32) + cdeg[ev].astype(jnp.float32) - c
-        return c / union
-    raise ValueError(f"unknown measure {measure!r}")
-
-
-def compute_similarities_densepad(
-    g: CSRGraph, measure: str = "cosine", chunk: int = 1 << 16
-) -> jax.Array:
-    """[legacy baseline] σ for every half-edge via the O(n·Δ) dense-padded
-    layout. Benchmark comparison path only — every production consumer
-    runs on the bucketed engine."""
-    if measure not in MEASURES:
-        raise ValueError(f"measure must be one of {MEASURES}")
-    nbr_mat, wgt_mat, m = padded_neighbors(g)
-    norms = closed_norms(g)
-    cdeg = g.closed_degrees()
-    total = g.m2
-    if total == 0:
-        return jnp.zeros((0,), jnp.float32)
-    # bound the transient [c, M] working set like the bucketed engine does
-    cap = max(CHUNK_ELEMS // max(2 * m, 1), 1)
-    cap = 1 << (cap.bit_length() - 1)
-    chunk = min(max(min(chunk, cap), 1), _pow2_bucket(total))
-    out = []
-    for s in range(0, total, chunk):
-        e = min(s + chunk, total)
-        pad = chunk - (e - s)
-        cu = jnp.pad(g.edge_u[s:e], (0, pad))
-        cv = jnp.pad(g.nbrs[s:e], (0, pad))
-        cw = jnp.pad(g.wgts[s:e], (0, pad))
-        sims = _edge_sims_chunk(cu, cv, cw, nbr_mat, wgt_mat, norms, cdeg,
-                                measure)
-        out.append(sims[: e - s])
-    return jnp.concatenate(out) if len(out) > 1 else out[0]

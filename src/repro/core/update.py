@@ -12,8 +12,7 @@ frontier (edges incident to touched endpoints), and the degree-bucketed
 in place, class migrations move a vertex between exactly two blocks, hub
 rows split/merge under the ``HUB_TILE`` rule, untouched blocks are reused
 outright). What remains per batch is O(m) host data movement (CSR
-reassembly, shifted NO copies, the CO merge) — measured crossover curves
-live in ``benchmarks/bench_update.py`` and ``BENCH_update.json``:
+reassembly, shifted NO copies, the CO merge). Part by part:
 
   * **similarity** — σ(u, v) depends only on N̄(u) and N̄(v), so an edit
     batch changes σ exactly for edges with a touched endpoint. The live

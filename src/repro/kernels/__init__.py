@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the SCAN service (``bucket_probe``, ``hamming``,
+``simhash``, ``triangle_count``), their pure-jnp oracles in ``ref.py``,
+and the per-call lane dispatch in ``ops.py``."""

@@ -31,7 +31,6 @@ from repro.kernels.triangle_count import masked_gram
 from repro.kernels.bucket_probe import bucket_probe
 from repro.kernels.simhash import simhash_pack
 from repro.kernels.hamming import hamming_distance, hamming_sigma
-from repro.kernels.flash_attention import flash_attention
 
 
 def _resolve(policy: Optional[ExecutionPolicy], op: str,
@@ -175,40 +174,3 @@ def simhash_edge_similarity_kernel(
                                 pad_to(sv, block, (0,)), be=block,
                                 interpret=pol.interpret(lane))[:e0]
     return hamming_sigma(diff, samples)
-
-
-def attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-    window: int = 0, bq: int = 128, bkv: int = 128,
-    policy: Optional[ExecutionPolicy] = None, lane: Optional[str] = None,
-) -> jax.Array:
-    """Flash attention over [bh, s, d] tensors (pads s and d to blocks)."""
-    pol, lane = _resolve(policy, "attention", lane)
-    if lane == LANE_REF:
-        # the oracle handles arbitrary shapes; no padding (or rescale) needed
-        return kref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    bh, sq, d0 = q.shape
-    skv = k.shape[1]
-    d_pad = max((d0 + 127) // 128 * 128, 128)
-    qp = pad_to(q, d_pad, (2,))
-    kp = pad_to(k, d_pad, (2,))
-    vp = pad_to(v, d_pad, (2,))
-    sq_p = (sq + bq - 1) // bq * bq
-    skv_p = (skv + bkv - 1) // bkv * bkv
-    # pad kv with zeros & mask via window/causal handled by padding at end:
-    # padded kv positions get score NEG_INF only under causal mask when
-    # k_pos > q_pos; for non-causal we must not attend padding — extend the
-    # causal guard by masking padded keys through an additive bias is not
-    # supported here, so we require exact multiples for non-causal use.
-    if not causal:
-        assert sq % bq == 0 and skv % bkv == 0, "pad seq for non-causal"
-    qp = pad_to(qp, sq_p, (1,))[:, :sq_p]
-    kp = pad_to(kp, skv_p, (1,))[:, :skv_p]
-    vp = pad_to(vp, skv_p, (1,))[:, :skv_p]
-    # scale uses true d0, not padded width (padding contributes zero dot)
-    out = flash_attention(
-        qp * (d_pad ** 0.5) / (d0 ** 0.5), kp, vp,
-        causal=causal, window=window, bq=bq, bkv=bkv,
-        interpret=pol.interpret(lane),
-    )
-    return out[:, :sq, :d0]

@@ -32,22 +32,3 @@ def hamming_distance_ref(sk_u: jax.Array, sk_v: jax.Array) -> jax.Array:
     """Differing bits per row of packed uint32 sketches, int32[e]."""
     x = jnp.bitwise_xor(sk_u, sk_v)
     return jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
-
-
-def flash_attention_ref(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-    window: int = 0
-) -> jax.Array:
-    d = q.shape[-1]
-    s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) / (d ** 0.5)
-    sq, skv = s.shape[-2], s.shape[-1]
-    q_pos = jnp.arange(sq)[:, None]
-    k_pos = jnp.arange(skv)[None, :]
-    mask = jnp.ones((sq, skv), dtype=bool)
-    if causal:
-        mask &= k_pos <= q_pos
-    if window > 0:
-        mask &= k_pos > q_pos - window
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p, v).astype(q.dtype)

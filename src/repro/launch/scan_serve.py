@@ -669,7 +669,7 @@ watermark. Chaos spec keys: crash, stall, slow, corrupt, delay
 --chaos-seed, so a failing seed is a regression test)."""
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     for name, fn in (("sweep", cmd_sweep), ("serve", cmd_serve),
@@ -767,7 +767,7 @@ def main():
                            help="wall-clock guard per request; tripping "
                            "it counts as an *unshed* timeout and fails "
                            "the run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.lane:
         # export rather than thread: the per-call REPRO_LANE read reaches
         # every dispatch site, including index *construction* paths that

@@ -1,7 +1,7 @@
 """Metrics primitives: counters, gauges, and log-bucketed histograms.
 
 Dependency-free (stdlib only) so every layer — core kernels, the serve
-stack, benchmarks, the CLI — can record into one
+stack, the CLI, the chip benchmark under ``bench/`` — can record into one
 :class:`MetricsRegistry` without import cycles or optional extras.
 
 Design constraints, in order:

@@ -86,16 +86,6 @@ def test_lane_matrix_simhash_and_hamming(lane):
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("lane", _HOST_LANES)
-def test_lane_matrix_attention(lane):
-    bh, s, d = 2, 128, 64
-    q, k, v = (jnp.asarray(RNG.standard_normal((bh, s, d)), jnp.float32)
-               for _ in range(3))
-    want = np.asarray(ops.attention(q, k, v, causal=True, lane=LANE_REF))
-    got = np.asarray(ops.attention(q, k, v, causal=True, lane=lane))
-    np.testing.assert_allclose(got, want, atol=2e-5)
-
-
 @pytest.mark.parametrize("weighted", [False, True])
 def test_full_similarity_pass_lane_identity(monkeypatch, weighted):
     """The whole σ engine (plan → groups → epilogue) under a forced

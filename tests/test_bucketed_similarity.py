@@ -22,7 +22,6 @@ from repro.core import (
     build_index,
     compute_similarities,
     compute_similarities_dense,
-    compute_similarities_densepad,
     edge_similarities_subset,
     from_edge_list,
     hub_ring_graph,
@@ -32,7 +31,7 @@ from repro.core import (
     triangle_counts,
 )
 from repro.core import similarity as sim_mod
-from repro.core.similarity import SimilarityPlan, densepad_operand_bytes
+from repro.core.similarity import SimilarityPlan
 
 try:
     import hypothesis
@@ -275,20 +274,16 @@ def test_operand_memory_beats_dense_padding():
     graphs the gap grows with n·Δ/m)."""
     g = hub_ring_graph(2048, 512)
     plan = plan_for(g)
-    dense_bytes = densepad_operand_bytes(g)
+    # dense-padded layout: int32 ids + float32 weights at n × (max degree
+    # rounded up to 8), plus float32 norms and int32 closed degrees
+    width = -(-int(np.asarray(g.degrees()).max()) // 8) * 8
+    dense_bytes = g.n * width * (4 + 4) + 8 * g.n
     assert dense_bytes >= 10 * plan.operand_bytes(), (
         dense_bytes, plan.operand_bytes())
     # and the bucketed layout stays O(m + n): blocks ≤ 2·m2 + floor·n slots
     slots = sum(int(np.prod(b.shape)) for b in plan.nbr_blocks)
     assert slots <= 2 * g.m2 + sim_mod.BUCKET_FLOOR * g.n + 2 * len(
         plan.widths) * sim_mod.HUB_TILE
-
-
-def test_densepad_legacy_path_agrees():
-    g = power_law_graph(120, 2.1, seed=8, weighted=True, hub_degree=48)
-    a = np.asarray(compute_similarities(g, "cosine"))
-    b = np.asarray(compute_similarities_densepad(g, "cosine"))
-    np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
 
 
 def test_jit_cache_hoisted_across_apply_delta_batches():

@@ -7,11 +7,15 @@ fingerprint; a kill-mid-snapshot test confirms ``.tmp`` wreckage is
 ignored and the prior version restores. These are the satellites of the
 fsync durability fix in ``repro.ckpt.checkpoint`` — os.rename used to be
 the only "commit", which survives a process crash but not a power cut.
+The checkpoint module's own round trip, retention and refusals close the
+file.
 """
 import asyncio
 import os
 import shutil
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -194,3 +198,47 @@ def test_fsync_helpers_roundtrip(tmp_path):
     checkpoint.fsync_file_then_dir(str(p))
     checkpoint.fsync_dir(str(tmp_path))
     assert p.read_bytes() == b"payload"
+
+
+# --------------------------------------------------------------------------
+# checkpointing
+# --------------------------------------------------------------------------
+def _tree(seed=0):
+    k = jax.random.PRNGKey(seed)
+    return {"a": jax.random.normal(k, (16, 8)),
+            "nested": {"b": jnp.arange(10, dtype=jnp.int32),
+                       "c": jax.random.normal(k, (4,)).astype(jnp.bfloat16)},
+            "count": jnp.int32(7)}
+
+
+def test_checkpoint_roundtrip(tmp_path):
+    t = _tree()
+    checkpoint.save(str(tmp_path), 3, t)
+    like = jax.tree.map(lambda x: jnp.zeros_like(x), t)
+    out = checkpoint.restore(str(tmp_path), 3, like)
+    for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_checkpoint_latest_and_gc(tmp_path):
+    t = _tree()
+    for s in (1, 2, 3, 4, 5):
+        checkpoint.save(str(tmp_path), s, t, keep=2)
+    assert checkpoint.latest_step(str(tmp_path)) == 5
+    kept = sorted(os.listdir(tmp_path))
+    assert kept == ["step_00000004", "step_00000005"]
+
+
+def test_checkpoint_no_partial_commit(tmp_path):
+    """A stale .tmp directory is never treated as a checkpoint."""
+    t = _tree()
+    checkpoint.save(str(tmp_path), 1, t)
+    os.makedirs(tmp_path / "step_00000002.tmp")
+    assert checkpoint.latest_step(str(tmp_path)) == 1
+
+
+def test_checkpoint_shape_mismatch_rejected(tmp_path):
+    checkpoint.save(str(tmp_path), 1, {"a": jnp.zeros((4,))})
+    with pytest.raises(ValueError):
+        checkpoint.restore(str(tmp_path), 1, {"a": jnp.zeros((5,))})

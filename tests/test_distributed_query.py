@@ -6,10 +6,13 @@ all-reduced label propagation; every merge is an associative min/max, so
 results must equal the single-device path *exactly* — same labels, same
 core mask, same cluster count, for every (μ, ε) including the extremes.
 
+The edge-sharded similarity pass
+(``repro.core.distributed.sharded_edge_similarities``) is held to the
+single-device σ the same way.
+
 Multi-device cases run in a subprocess with
 ``--xla_force_host_platform_device_count=8`` (the parent process must keep
-its real single-device view; see tests/test_distribution.py for the same
-pattern).
+its real single-device view).
 """
 import os
 import subprocess
@@ -74,6 +77,30 @@ _PROG = textwrap.dedent("""
 """)
 
 
+_SIM_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax, jax.numpy as jnp
+    from repro.core import random_graph, power_law_graph, compute_similarities
+    from repro.core.similarity import plan_for
+    from repro.core.distributed import sharded_edge_similarities
+
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    # edge-parallel shard_map over the degree-bucketed groups; class blocks
+    # replicated, ragged group sizes padded to the axis size internally
+    for g in (random_graph(48, 6.0, seed=3),
+              power_law_graph(64, 2.1, seed=4, hub_degree=24)):
+        with mesh:
+            sims_sharded = sharded_edge_similarities(g, plan_for(g), mesh)
+        sims_ref = compute_similarities(g)
+        err = float(jnp.max(jnp.abs(sims_sharded - sims_ref)))
+        assert err < 1e-5, err
+    print("SCAN_SHARD_OK", err)
+""")
+
+
 def _run_subprocess(prog: str) -> subprocess.CompletedProcess:
     return subprocess.run(
         [sys.executable, "-c", prog],
@@ -129,3 +156,12 @@ def test_query_mesh_rejects_oversubscription():
 
     with pytest.raises(ValueError, match="devices"):
         query_mesh(4096)
+
+
+@pytest.mark.slow
+def test_sharded_edge_similarities_multidevice():
+    """Edge-sharded SCAN similarity ≡ single-device, on an 8-device (2×4)
+    host-platform mesh sharded over its ``data`` axis."""
+    r = _run_subprocess(_SIM_PROG)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "SCAN_SHARD_OK" in r.stdout

@@ -10,7 +10,6 @@ from repro.kernels.bucket_probe import bucket_probe
 from repro.kernels.triangle_count import masked_gram
 from repro.kernels.simhash import simhash_pack
 from repro.kernels.hamming import hamming_distance, hamming_sigma
-from repro.kernels.flash_attention import flash_attention
 
 RNG = np.random.default_rng(0)
 
@@ -82,41 +81,6 @@ def test_hamming_sweep(e, words, k):
     sigma = np.asarray(hamming_sigma(diff, k))
     np.testing.assert_allclose(sigma, np.cos(np.pi * np.asarray(want) / k),
                                atol=1e-6)
-
-
-@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 128),
-                                           (False, 0)])
-def test_flash_attention_sweep(dtype, causal, window):
-    bh, s, d = 3, 256, 128
-    q = RNG.standard_normal((bh, s, d)).astype(np.float32)
-    k = RNG.standard_normal((bh, s, d)).astype(np.float32)
-    v = RNG.standard_normal((bh, s, d)).astype(np.float32)
-    qq, kk, vv = (jnp.asarray(x).astype(dtype) for x in (q, k, v))
-    out = flash_attention(qq, kk, vv, causal=causal, window=window,
-                          interpret=True)
-    want = kref.flash_attention_ref(qq, kk, vv, causal=causal, window=window)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(want, np.float32), atol=tol)
-
-
-def test_flash_vs_model_attention():
-    """Pallas serving kernel ≡ the model's jnp attention (same semantics)."""
-    from repro.models import layers as L
-    b, s, h, d = 2, 256, 4, 64
-    q = jnp.asarray(RNG.standard_normal((b, s, h, d)), jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((b, s, h, d)), jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((b, s, h, d)), jnp.float32)
-    model_out = L.attention(q, k, v, causal=True, impl="dense")
-    # kernel path: fold heads into batch
-    qf = jnp.moveaxis(q, 2, 1).reshape(b * h, s, d)
-    kf = jnp.moveaxis(k, 2, 1).reshape(b * h, s, d)
-    vf = jnp.moveaxis(v, 2, 1).reshape(b * h, s, d)
-    kern = ops.attention(qf, kf, vf, causal=True)
-    kern = jnp.moveaxis(kern.reshape(b, h, s, d), 1, 2)
-    np.testing.assert_allclose(np.asarray(kern), np.asarray(model_out),
-                               atol=2e-5)
 
 
 def test_kernel_simhash_statistically_sound():
