@@ -28,9 +28,9 @@ One chip:
    of the edited graph on a second name, 8 global requests from it,
    ``refine``. Checks: compiled-lane counters of ``bucket_probe`` and
    ``hamming`` above 0 and no interpret counter; σ under the auto lanes
-   bit-identical to the ref lane on 2,048 edges of every kernel-routed
-   group; every served answer (global labels and cores, seed rows) equal
-   to ``query_batch``; σ̂ of the compiled lane equal to the ref lane's on
+   bit-identical to the ref lane on 2,048 edges of every group; every
+   served answer (global labels and cores, seed rows) equal to
+   ``query_batch``; σ̂ of the compiled lane equal to the ref lane's on
    every sketched half-edge; the refine (a from-scratch exact build of the
    edited graph) equal in fingerprint to the index the deltas maintained.
 
@@ -74,7 +74,7 @@ N_AFTER = 8     # global requests after the deltas and from the
 DELTA_BATCHES = 2
 DELTA_EDITS = 64
 APPROX = "simhash:256"
-# edges per kernel-routed (P, T) group whose σ the full-size run recomputes
+# edges per (P, T) group whose σ the full-size run recomputes
 # on the ref lane (the whole ref-lane build there takes ~530 s on one v5e;
 # the small graph compares every edge)
 REF_SAMPLE = 2048
@@ -276,26 +276,17 @@ def check_served(index, g, served, seeds, what: str) -> None:
 
 
 def ref_lane_sample(g, plan, sims, ref_pol, per_group: int) -> None:
-    """σ of the kernel-routed groups on the ``ref`` lane, for up to
-    ``per_group`` evenly spaced edges of every group, vs ``sims`` (built
-    under the auto lanes) bit for bit."""
+    """σ of every group on the ``ref`` lane, for up to ``per_group``
+    evenly spaced edges of each, vs ``sims`` (built under the auto lane,
+    the compiled kernel on a TPU) bit for bit."""
     import numpy as np
-    from repro.backend.policy import LANE_COMPILED, default_policy
-    from repro.backend.padding import np_pow2ceil
 
     eu = np.asarray(g.edge_u, np.int64)
     ev = np.asarray(g.nbrs, np.int64)
-    pu, _, keys = plan.route(eu, ev)
-    pe = np_pow2ceil(plan.vtiles[pu]) * np.asarray(plan.widths)[
-        plan.vclass[pu]]
-    pol = default_policy()
-    kernel = np.zeros(len(eu), bool)
-    for w in np.unique(pe):
-        if pol.lane("bucket_probe", width=int(w)) == LANE_COMPILED:
-            kernel |= pe == w
+    _, _, keys = plan.route(eu, ev)
     picks = []
-    for key in np.unique(keys[kernel]):
-        idx = np.flatnonzero(kernel & (keys == key))
+    for key in np.unique(keys):
+        idx = np.flatnonzero(keys == key)
         take = np.linspace(0, len(idx) - 1, min(per_group, len(idx)))
         picks.append(idx[np.unique(take.astype(np.int64))])
     sel = np.concatenate(picks)
@@ -303,9 +294,8 @@ def ref_lane_sample(g, plan, sims, ref_pol, per_group: int) -> None:
                          policy=ref_pol)
     check(np.array_equal(np.asarray(ref).view(np.uint32),
                          np.asarray(sims)[sel].view(np.uint32)),
-          f"full sigma auto lanes == ref lane on {len(sel)} edges sampled "
-          f"from all {len(picks)} kernel-routed groups "
-          f"({int(kernel.sum())} edges; bit-identical)")
+          f"full sigma auto lane == ref lane on {len(sel)} edges sampled "
+          f"from all {len(picks)} groups (bit-identical)")
 
 
 def device_calls(engine) -> str:

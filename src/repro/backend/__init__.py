@@ -6,7 +6,7 @@
   (``REPRO_LANE`` env, ``EngineConfig(lane=...)``, ``scan_serve --lane``),
   ``backend.lane.*`` counters.
 * :mod:`repro.backend.profile` — :class:`AutotuneProfile` calibrated
-  thresholds (default = the legacy constants) + the one-shot
+  shapes (default = the legacy constants) + the one-shot
   :func:`autotune` microbenchmark; profiles persist as a manifest leaf in
   ``IndexStore`` snapshots.
 * :mod:`repro.backend.padding` — the shared padding / pow2 shape helpers.

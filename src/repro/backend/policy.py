@@ -14,9 +14,9 @@ hard-coded at each call site. The policy centralizes all of it:
   to its available lanes: ``ref`` (pure-jnp oracle),
   ``pallas-interpret`` (kernel body emulated on host), and
   ``pallas-compiled`` (real accelerator dispatch);
-* **calibrated thresholds** — an :class:`~repro.backend.profile
-  .AutotuneProfile` of block shapes and class-dispatch cutoffs
-  (default = the legacy constants);
+* **calibrated shapes** — an :class:`~repro.backend.profile
+  .AutotuneProfile` of block shapes and tile widths (default = the
+  legacy constants);
 * **a forced-lane override** — the ``REPRO_LANE`` environment variable
   (read per call, so tests and subprocesses can pin a lane) or an
   explicit ``forced_lane=`` (``EngineConfig(lane=...)`` / ``scan_serve
@@ -65,7 +65,7 @@ def _check_lane(lane: str) -> str:
 
 
 class ExecutionPolicy:
-    """Per-call lane resolution + thresholds + lane counters.
+    """Per-call lane resolution + profile shapes + lane counters.
 
     ``registry`` is an optional :class:`repro.obs.MetricsRegistry`; when
     present every resolved dispatch counts under
@@ -105,21 +105,18 @@ class ExecutionPolicy:
         interpret (host emulation of the same kernel body) elsewhere."""
         return LANE_COMPILED if self.platform() == "tpu" else LANE_INTERPRET
 
-    def lane(self, op: str, *, width: Optional[int] = None) -> str:
+    def lane(self, op: str) -> str:
         """Routing-site decision: which lane answers ``op``.
 
         Forced lane wins (clamped to the op's registered lanes). Otherwise
-        on TPU the Pallas kernel takes groups at least
-        ``profile.probe_min_width`` wide; everything else — including every
-        non-TPU platform — runs the jnp reference engine.
+        a TPU runs the compiled kernel and every other platform the jnp
+        reference engine.
         """
         avail = OPS.get(op, (LANE_REF,))
         forced = self.forced_lane()
         if forced is not None:
             return forced if forced in avail else LANE_REF
         if self.platform() == "tpu" and LANE_COMPILED in avail:
-            if width is not None and width < self.profile.probe_min_width:
-                return LANE_REF
             return LANE_COMPILED
         return LANE_REF
 

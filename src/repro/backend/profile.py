@@ -1,16 +1,16 @@
-"""Calibrated dispatch thresholds: the :class:`AutotuneProfile`.
+"""Calibrated dispatch shapes: the :class:`AutotuneProfile`.
 
-Every knob the execution policy consults when routing a hot op — the hub
-storage tile, the class-dispatch cutoff, the per-kernel block shapes —
-lives in one frozen dataclass. The **default instance is bit-for-bit
-today's constants** (``HUB_TILE=2048``, gram/simhash blocks 128, probe
-blocks 256, hamming block 1024): an old checkpoint without a persisted
-profile, or a policy that never autotuned, behaves exactly like the code
-did before this subsystem existed.
+Every knob the execution policy consults when running a hot op — the hub
+storage tile and the per-kernel block shapes — lives in one frozen
+dataclass. The **default instance is bit-for-bit today's constants**
+(``HUB_TILE=2048``, gram/simhash blocks 128, probe blocks 256, hamming
+block 1024): an old checkpoint without a persisted profile, or a policy
+that never autotuned, behaves exactly like the code did before this
+subsystem existed.
 
 Profiles persist as a versioned JSON manifest leaf next to the index
 (``repro.serve.store.IndexStore``), so a served index remembers the
-thresholds it was tuned with; :func:`autotune` produces a fresh profile
+shapes it was tuned with; :func:`autotune` produces a fresh profile
 from a one-shot microbenchmark sweep under a ``backend.autotune`` span.
 
 The profile only moves *shapes* (padding, tiling, chunking), never math:
@@ -28,16 +28,12 @@ PROFILE_VERSION = 1
 
 @dataclasses.dataclass(frozen=True)
 class AutotuneProfile:
-    """Dispatch thresholds for one platform. Defaults = legacy constants."""
+    """Dispatch shapes for one platform. Defaults = legacy constants."""
 
     version: int = PROFILE_VERSION
     platform: str = "default"     # backend the sweep ran on ("default" = untuned)
     # -- similarity-plan shape (core.similarity) ---------------------------
     hub_tile: int = 2048          # storage tile width for hub rows
-    # -- class-dispatch cutoff ---------------------------------------------
-    # minimum probe-row element count before the auto policy (TPU) routes a
-    # similarity group to the Pallas probe kernel instead of the jnp engine
-    probe_min_width: int = 256
     # -- kernel block shapes ------------------------------------------------
     gram_block: int = 128         # masked_gram bm/bn/bk (triangle_count op)
     probe_be: int = 256           # bucket_probe edge-block
@@ -53,6 +49,8 @@ class AutotuneProfile:
 
     @classmethod
     def from_json(cls, payload: str) -> "AutotuneProfile":
+        """The profile a stored payload describes; keys this version no
+        longer has (such as a retired dispatch cutoff) are dropped."""
         data = json.loads(payload)
         names = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in data.items() if k in names})

@@ -114,7 +114,7 @@ def sharded_edge_similarities(
         # graphs hit the same compiled shard_map shapes
         pe = sp * plan.widths[cp]
         te = st * plan.widths[ct]
-        lane = pol.lane("bucket_probe", width=pe)
+        lane = pol.lane("bucket_probe")
         pol.note("bucket_probe", lane)
         cap = max(sim_mod.CHUNK_ELEMS // max(pe + te, 1), 1)
         cap = 1 << (cap.bit_length() - 1)

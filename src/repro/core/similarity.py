@@ -71,11 +71,11 @@ Per-group lane choice goes through :class:`repro.backend.ExecutionPolicy`
 (``plan.edge_sims(..., policy=...)``): the ``ref`` lane is the jnp
 searchsorted engine below, the Pallas lanes run the sorted-probe kernel
 (:mod:`repro.kernels.bucket_probe`, the masked-gram pattern extended with
-target-tile streaming) on the same gathered operands — auto-dispatch
-sends groups at least ``profile.probe_min_width`` wide to the compiled
-kernel on TPU, and ``REPRO_LANE`` pins a lane everywhere. All lanes are
-bit-identical on unweighted σ (ULP on weighted), so lane choice never
-moves a fingerprint.
+target-tile streaming) on the same gathered operands — on a TPU every
+group runs the compiled kernel, elsewhere the ``ref`` lane, and
+``REPRO_LANE`` pins a lane everywhere. All lanes are bit-identical on
+unweighted σ (ULP on weighted), so lane choice never moves a
+fingerprint.
 
 Supported measures (paper §2.1/§4.1.1):
   * ``cosine``  — weighted cosine over closed neighborhoods (w(x,x)=1);
@@ -475,8 +475,9 @@ class SimilarityPlan:
         counted under ``similarity.chunks``, and those dispatched before
         the previous chunk was done under ``similarity.chunks_queued``.
         The edges a chunk program computed count under
-        ``similarity.sigma_computed``. Without a tracer nothing is timed
-        or counted.
+        ``similarity.sigma_computed``, those a Pallas lane computed also
+        under ``similarity.sigma_kernel``. Without a tracer nothing is
+        timed or counted.
         """
         if measure not in MEASURES + ("_count",):
             raise ValueError(f"measure must be one of {MEASURES}")
@@ -495,7 +496,7 @@ class SimilarityPlan:
             bounds = np.flatnonzero(np.diff(keys[order])) + 1
             groups = np.split(order, bounds)
 
-        shapes, runs, nslots = [], [], 0
+        shapes, runs, nslots, kernel = [], [], 0, 0
         for idx in groups:
             cp = int(self.vclass[pu[idx[0]]])
             ct = int(self.vclass[pv[idx[0]]])
@@ -503,9 +504,11 @@ class SimilarityPlan:
             st = _pow2ceil(int(self.vtiles[pv[idx[0]]]))
             pe = sp * self.widths[cp]
             te = st * self.widths[ct]
-            lane = pol.lane("bucket_probe", width=pe)
+            lane = pol.lane("bucket_probe")
             pol.note("bucket_probe", lane)
             shapes.append((pe, te, lane, len(idx)))
+            if lane != LANE_REF:
+                kernel += len(idx)
             cap = max(CHUNK_ELEMS // max(pe + te, 1), 1)
             cap = 1 << (cap.bit_length() - 1)
             if lane == LANE_COMPILED:
@@ -575,6 +578,7 @@ class SimilarityPlan:
                 tracer.inc("similarity.chunks_queued", queued)
         if tracer is not None:
             tracer.inc("similarity.sigma_computed", total)
+            tracer.inc("similarity.sigma_kernel", kernel)
         object.__setattr__(self, "last_group_shapes", tuple(shapes))
         return _in_input_order(slots, jnp.asarray(where))
 

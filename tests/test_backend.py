@@ -21,7 +21,10 @@ from repro.backend.policy import (LANE_COMPILED, LANE_INTERPRET, LANE_REF,
                                   OPS, ExecutionPolicy, default_policy)
 from repro.backend.profile import (DEFAULT_PROFILE, PROFILE_VERSION,
                                    AutotuneProfile, autotune)
-from repro.core import compute_similarities, random_graph
+from repro.backend.padding import np_pow2ceil
+from repro.core import (compute_similarities, plan_for, power_law_graph,
+                        random_graph)
+from repro.core import similarity as sim_mod
 from repro.kernels import ops
 from repro.obs import MetricsRegistry
 
@@ -102,6 +105,98 @@ def test_full_similarity_pass_lane_identity(monkeypatch, weighted):
         np.testing.assert_array_equal(got, want)
 
 
+def _group_widths(plan, eu, ev):
+    """(probe width, target width) of each half-edge's σ group."""
+    pu, pv, _ = plan.route(eu, ev)
+    widths = np.asarray(plan.widths)
+    return (np_pow2ceil(plan.vtiles[pu]) * widths[plan.vclass[pu]],
+            np_pow2ceil(plan.vtiles[pv]) * widths[plan.vclass[pv]])
+
+
+# a power-law graph whose narrow probe rows (8–128 wide) meet targets from
+# their own width up to a two-tile hub (degree 2,297 > HUB_TILE: 4,096 wide)
+_NARROW = [(8, 8), (16, 32), (32, 64), (64, 128), (128, 256), (8, 4096),
+           (128, 4096)]
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_narrow_groups_pallas_lane_matches_ref(monkeypatch, weighted):
+    """``half_edge_sims`` under the Pallas lane reproduces the ref lane on
+    groups whose probe rows are 8–128 wide, one of them into a multi-tile
+    hub: bit for bit unweighted, to ULP weighted. Every half-edge the
+    Pallas pass computed counts under ``similarity.sigma_kernel``, none
+    of the ref pass's."""
+    from repro.obs import Tracer
+
+    monkeypatch.delenv("REPRO_LANE", raising=False)
+    g = power_law_graph(2500, avg_degree=6.0, hub_degree=2200, seed=5,
+                        weighted=weighted)
+    plan = plan_for(g)
+    eu, ev = np.asarray(g.edge_u, np.int64), np.asarray(g.nbrs, np.int64)
+    pe, te = _group_widths(plan, eu, ev)
+    # twins share their group, so a set of whole groups is twin-closed
+    sel = np.flatnonzero(np.isin(pe * (1 << 20) + te,
+                                 [p * (1 << 20) + t for p, t in _NARROW]))
+    runs = {}
+    for lane in (LANE_REF, LANE_INTERPRET):
+        tr = Tracer(MetricsRegistry())
+        sims = plan.half_edge_sims(
+            g, sel, policy=ExecutionPolicy(forced_lane=lane), tracer=tr)
+        runs[lane] = (np.asarray(sims), tr.registry.snapshot()["counters"])
+    assert {(p, t) for p, t, _, _ in plan.last_group_shapes} == set(_NARROW)
+    want, got = runs[LANE_REF][0], runs[LANE_INTERPRET][0]
+    if weighted:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+    for lane, kernel in ((LANE_REF, 0), (LANE_INTERPRET, len(sel) // 2)):
+        counters = runs[lane][1]
+        assert counters["similarity.sigma_computed"] == len(sel) // 2
+        assert counters["similarity.sigma_kernel"] == kernel
+
+
+@pytest.mark.parametrize("platform,forced,want", [
+    ("tpu", None, LANE_COMPILED),
+    ("cpu", None, LANE_REF),
+    ("tpu", LANE_REF, LANE_REF),
+    ("cpu", LANE_INTERPRET, LANE_INTERPRET),
+])
+def test_sigma_groups_of_every_width_take_the_platform_lane(
+        monkeypatch, platform, forced, want):
+    """The σ pass's lane depends on the platform alone: on a (mocked) TPU
+    every group, from 8-wide probe rows to hub tiles, runs the compiled
+    kernel; elsewhere the ref lane; a forced lane still wins. The chunk
+    programs are stubbed, so nothing runs on the mocked chip."""
+    from unittest import mock
+
+    if forced is None:
+        monkeypatch.delenv("REPRO_LANE", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_LANE", forced)
+    seen = set()
+
+    def stub(lane):
+        def chunk(edges, *operands, **static):
+            assert static.get("interpret", True) == (lane != LANE_COMPILED)
+            seen.add(lane)
+            return jax.numpy.zeros(edges.shape[1], jax.numpy.float32)
+        return chunk
+
+    monkeypatch.setattr(sim_mod, "_bucket_sims_chunk", stub(LANE_REF))
+    monkeypatch.setattr(sim_mod, "_bucket_sims_chunk_pallas",
+                        stub(LANE_COMPILED if platform == "tpu"
+                             else LANE_INTERPRET))
+    g = power_law_graph(2500, avg_degree=6.0, hub_degree=2200, seed=5)
+    plan = plan_for(g)
+    with mock.patch("jax.default_backend", return_value=platform):
+        plan.edge_sims(g.edge_u, g.nbrs, g.wgts)
+    shapes = plan.last_group_shapes
+    assert {pe for pe, _, _, _ in shapes} >= {8, 16, 32, 64, 128, 256}
+    assert max(te for _, te, _, _ in shapes) == 4096
+    assert {lane for _, _, lane, _ in shapes} == {want} == seen
+
+
 # ---------------------------------------------------------------------------
 # per-call resolution: REPRO_LANE, clamping, platform
 # ---------------------------------------------------------------------------
@@ -112,9 +207,9 @@ def test_env_lane_read_per_call(monkeypatch):
     # the same policy object changes its answer when the env changes —
     # nothing is frozen at construction
     monkeypatch.setenv("REPRO_LANE", LANE_REF)
-    assert pol.lane("bucket_probe", width=1 << 20) == LANE_REF
+    assert pol.lane("bucket_probe") == LANE_REF
     monkeypatch.setenv("REPRO_LANE", LANE_INTERPRET)
-    assert pol.lane("bucket_probe", width=1) == LANE_INTERPRET
+    assert pol.lane("bucket_probe") == LANE_INTERPRET
     assert pol.kernel_lane("hamming") == LANE_INTERPRET
     monkeypatch.setenv("REPRO_LANE", "not-a-lane")
     with pytest.raises(ValueError, match="unknown lane"):
@@ -143,7 +238,7 @@ def test_forced_interpret_lane_refused_on_tpu(monkeypatch, via):
         monkeypatch.delenv("REPRO_LANE", raising=False)
         pol = ExecutionPolicy(forced_lane=LANE_INTERPRET)
     with mock.patch("jax.default_backend", return_value="tpu"):
-        for resolve in (lambda: pol.lane("bucket_probe", width=1 << 20),
+        for resolve in (lambda: pol.lane("bucket_probe"),
                         lambda: pol.kernel_lane("hamming"), pol.describe):
             with pytest.raises(RuntimeError, match="pallas-interpret"):
                 resolve()
@@ -223,10 +318,10 @@ from repro.backend.policy import (LANE_COMPILED, LANE_INTERPRET, LANE_REF,
 pol = ExecutionPolicy()
 with mock.patch("jax.default_backend", return_value="tpu"):
     assert pol.kernel_lane("bucket_probe") == LANE_COMPILED
-    assert pol.lane("bucket_probe", width=1 << 20) == LANE_COMPILED
+    assert pol.lane("bucket_probe") == LANE_COMPILED
 with mock.patch("jax.default_backend", return_value="cpu"):
     assert pol.kernel_lane("bucket_probe") == LANE_INTERPRET
-    assert pol.lane("bucket_probe", width=1 << 20) == LANE_REF
+    assert pol.lane("bucket_probe") == LANE_REF
 print("OK")
 """
     env = dict(os.environ)

@@ -70,6 +70,44 @@ def test_old_checkpoint_without_leaf_defaults(tmp_path, monkeypatch):
     assert index2.n == index.n
 
 
+def test_stored_profile_with_a_retired_key_still_loads(tmp_path,
+                                                      monkeypatch):
+    """A snapshot saved while the profile still had ``probe_min_width``
+    (the retired width cutoff of the σ pass's lane choice) loads: the
+    retired key is dropped, every other stored value is kept, and an
+    untuned stored profile equals today's default."""
+    import json
+
+    import numpy as np
+
+    def legacy(profile):
+        data = json.loads(profile.to_json())
+        data["probe_min_width"] = 256
+        return np.frombuffer(json.dumps(data, sort_keys=True).encode(),
+                             np.uint8)
+
+    real_to_tree = store_mod._to_tree
+
+    def legacy_to_tree(*args):
+        tree = real_to_tree(*args)
+        tree["backend_profile"] = legacy(args[-1])
+        return tree
+
+    monkeypatch.setattr(store_mod, "_to_tree", legacy_to_tree)
+    g = _graph(seed=7)
+    index = build_index(g, "cosine")
+    store = IndexStore(str(tmp_path))
+    tuned = AutotuneProfile(platform="tpu", probe_be=128)
+    store.save(index, g, profile=tuned)
+    store.save(index, g, profile=DEFAULT_PROFILE)
+    monkeypatch.undo()
+    assert b"probe_min_width" in bytes(legacy(DEFAULT_PROFILE))
+    assert store.profile(version=0) == tuned
+    assert store.profile() == DEFAULT_PROFILE
+    index2, _, _ = store.load()
+    assert index2.n == index.n
+
+
 def test_profile_mismatch_surfaces_in_status(tmp_path):
     """Restore under a policy tuned differently than the snapshot: the
     service flags the mismatch in status(), keeps serving on the policy's
